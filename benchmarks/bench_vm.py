@@ -285,7 +285,7 @@ def bench_fleet_pallas_msg(n: int = 64, laps: int = 4, service_every: int = 8):
 
 def bench_fleet_pallas_ann(n: int = 64):
     """Vector/DSP regime: every node grinds a 4->4 fixed-point ANN layer
-    (``vecfold`` on the MXU path) plus a ``dotprod`` reduction per
+    (``vecfold``) plus a ``dotprod`` reduction per
     iteration — the paper's tiny-ML node workload, fully claimed by the
     kernel.  Records steps/s and the in-kernel vs lax-tail split; the CI
     gate holds ``bailed_frac`` under 5%."""

@@ -24,6 +24,8 @@ def main(argv=None) -> None:
     ap.add_argument("--json-dir", default=".",
                     help="directory for BENCH_<module>.json (\"\" disables)")
     args = ap.parse_args(argv)
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     names = args.only.split(",") if args.only else MODULES
 
     print("name,us_per_call,derived")

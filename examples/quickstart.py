@@ -11,9 +11,11 @@ import numpy as np
 
 from repro.config import VMConfig
 from repro.core.vm import REXAVM
+from repro.launch.cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     cfg = VMConfig(cs_size=8192, steps_per_slice=2048)
     vm = REXAVM(cfg, backend="jit")   # "oracle" for the pure-Python twin
 

@@ -36,6 +36,7 @@ from repro.config import VMConfig
 from repro.core.vm import FleetVM, REXAVM
 from repro.exec import Executive, ExecutiveConfig, install_services
 from repro.launch.mesh import make_node_mesh
+from repro.launch.cache import enable_compile_cache
 
 CFG = VMConfig(cs_size=8192, steps_per_slice=2048)
 ECFG = ExecutiveConfig(quantum=256, slices=8)
@@ -117,13 +118,14 @@ def build_fleet(defects, mesh, executive=None):
 
 
 def main():
+    enable_compile_cache()
     defects = [0.1, 0.35, 0.6, 0.85]
     n_sensors = len(defects)
     collector = n_sensors
 
-    # On a multi-device host (e.g. XLA_FLAGS=--xla_force_host_platform_
-    # device_count=8) the node axis shards across the mesh; on one device
-    # the same code runs unsharded.  Non-divisible fleets replicate.
+    # On a multi-chip host (a four-chip v5e, say) the node axis shards
+    # across the chips; on one device the same code runs unsharded.
+    # Non-divisible fleets replicate.
     mesh = make_node_mesh() if len(jax.devices()) > 1 else None
 
     fleet, ex, svcs = build_fleet(defects, mesh, executive=ECFG)

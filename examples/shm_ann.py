@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.config import VMConfig
 from repro.core.vm import REXAVM
+from repro.launch.cache import enable_compile_cache
 
 
 def simulate_echo(dist: float, rng, n=48):
@@ -61,6 +62,7 @@ def node_program(w):
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     w = train_readout(rng)
     true_defect = 0.62

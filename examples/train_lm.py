@@ -24,6 +24,7 @@ from repro.resilience.voting import ReplicaVoter
 from repro.train.data import pipeline_for
 from repro.train.train_step import init_train_state, make_train_step
 from repro.train.trainer import Trainer
+from repro.launch.cache import enable_compile_cache
 
 SIZES = {
     # ~104M params
@@ -38,6 +39,7 @@ SIZES = {
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--size", default="20m", choices=list(SIZES))
     ap.add_argument("--steps", type=int, default=200)

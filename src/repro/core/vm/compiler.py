@@ -18,6 +18,7 @@ runs on device in the jitted interpreter or in the Python oracle.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -182,6 +183,13 @@ class LocalSym:
     relocs: list[int] = field(default_factory=list)
 
 
+@functools.lru_cache(maxsize=8)
+def _core_tables(names: tuple[str, ...]):
+    """The PHT and LST over an ISA's core words.  Read-only once built, so
+    every compiler of one ISA (one per fleet node) shares them."""
+    return PerfectHashTable(list(names)), LinearSearchTable(list(names))
+
+
 class Compiler:
     """Per-VM compiler instance bound to ISA + IOS registries (paper: the
     compiler is always bundled with the VM)."""
@@ -197,9 +205,7 @@ class Compiler:
         self.fios = fios or FiosRegistry()
         self.dios = dios or DiosRegistry(0)
         self.dictionary = Dictionary()
-        names = [w.name for w in self.isa.words]
-        self.pht = PerfectHashTable(names)
-        self.lst = LinearSearchTable(names)
+        self.pht, self.lst = _core_tables(tuple(w.name for w in self.isa.words))
         self.lookup_mode = lookup
         self.words_compiled = 0   # MCPS accounting (paper Tab. 9)
         self._cur_tok: Token | None = None        # diagnostics source map

@@ -323,8 +323,9 @@ def get_pallas_engine(
 
 class _PallasObsEngine(NamedTuple):
     """Counting twin of :class:`_PallasEngine`: the obs variant of the
-    kernel (extra VMEM histogram output) plus a counting lax tail, phased
-    as schedule/execute so the fleet's obs round can trace each phase."""
+    kernel (a retirement histogram in each node's row) plus a counting
+    lax tail, phased as schedule/execute so the fleet's obs round can
+    trace each phase."""
 
     schedule: Callable   # jit: S -> (S, found)
     execute: Callable    # jit (static steps): (S, steps, found) -> (S, ExecAux)
@@ -401,7 +402,7 @@ class PallasSliceExecutor:
     ``interpret=None`` auto-selects: compiled on TPU (or when
     ``repro.kernels.set_kernels("on")`` forces kernels), Pallas interpreter
     otherwise — the CPU-testable path pinned byte-exact by
-    tests/test_vm_pallas.py.
+    tests/test_vm_pallas.py.  Interpret mode on a TPU backend raises.
     """
 
     backend = "pallas"
@@ -422,10 +423,10 @@ class PallasSliceExecutor:
         self.elide_checks = elide_checks
         self.interp = interp_for(cfg, isa, elide_checks)
         self._isa_arg = isa
+        from repro.kernels import check_interpret, use_kernels
         if interpret is None:
-            from repro.kernels import use_kernels
             interpret = not use_kernels()
-        self.interpret = interpret
+        self.interpret = check_interpret(interpret)
         engine = get_pallas_engine(cfg, isa, mesh, interpret, elide_checks)
         self.run_slice_batched = engine.plain
         self.run_slice_batched_aux = engine.aux

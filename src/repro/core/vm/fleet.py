@@ -44,6 +44,7 @@ of one program along the node axis with voting instead of routing — see
 from __future__ import annotations
 
 import functools
+import warnings
 from dataclasses import dataclass
 
 import jax
@@ -616,6 +617,12 @@ class FleetVM:
                 if self.n % ndev == 0
                 else PartitionSpec()
             )
+            if self.n % ndev:
+                warnings.warn(
+                    f"{self.n} nodes do not divide over {ndev} devices: the "
+                    f"whole fleet state is replicated on every device",
+                    stacklevel=2,
+                )
             self._sharding = NamedSharding(mesh, spec)
         isa = self.nodes[0].isa
         if any(vm.isa is not isa for vm in self.nodes):

@@ -79,7 +79,7 @@ class REXAVM:
         self.interp = getattr(self.executor, "interp", None)
         self.oracle = getattr(self.executor, "oracle", None)
         # Host-canonical numpy state.
-        self.state: VMState = vms.to_numpy(vms.init_state(self.cfg, seed))
+        self.state: VMState = vms.init_state(self.cfg, seed)
         # Cell 0 = canonical `end` (task return-to-zero convention).
         self.state.cs[0] = self.isa.enc_op("end")
         self.frames.allocate(1)  # reserve cell 0

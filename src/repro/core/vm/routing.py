@@ -94,10 +94,11 @@ def build_router(cfg: VMConfig, isa: ISA | None = None, obs: bool = False):
         # Segmented rank via one stable sort (O(NT log NT), no (NT, N)
         # incidence matrix): group valid sends by destination — invalid
         # entries sort to the tail — keep k-order within each group, then
-        # rank = position - segment start.
+        # rank = position - segment start.  The sort key is the destination
+        # alone (stability keeps k-order): a (dst, k) composite overflows
+        # int32 once N * N * T reaches 2^31 (16,384 nodes of 8 tasks).
         k = jnp.arange(NT, dtype=I32)
-        key = jnp.where(vf, df * NT + k, N * NT + k)
-        order = jnp.argsort(key)
+        order = jnp.argsort(jnp.where(vf, df, N), stable=True)
         pos = jnp.arange(NT, dtype=I32)
         sd = df[order]
         is_start = jnp.concatenate(

@@ -64,38 +64,41 @@ class VMState(NamedTuple):
 
 
 def init_state(cfg: VMConfig, seed: int = 1) -> VMState:
+    """A fresh, mutable host (numpy) machine.  Built without touching a
+    device: one REXAVM per node, and a device round trip per field adds up
+    to minutes at chip-scale fleets."""
     T = cfg.max_tasks
     return VMState(
-        cs=jnp.zeros(cfg.cs_size, jnp.int32),
-        mem=jnp.zeros(cfg.mem_size, jnp.int32),
-        ds=jnp.zeros((T, cfg.ds_size), jnp.int32),
-        rs=jnp.zeros((T, cfg.rs_size), jnp.int32),
-        fs=jnp.zeros((T, cfg.fs_size), jnp.int32),
-        dsp=jnp.zeros(T, jnp.int32),
-        rsp=jnp.zeros(T, jnp.int32),
-        fsp=jnp.zeros(T, jnp.int32),
-        pc=jnp.zeros(T, jnp.int32),
-        tstatus=jnp.full(T, ST_FREE, jnp.int32),
-        prio=jnp.zeros(T, jnp.int32),
-        deadline=jnp.zeros(T, jnp.int32),
-        timeout=jnp.zeros(T, jnp.int32),
-        ev_addr=jnp.zeros(T, jnp.int32),
-        ev_val=jnp.zeros(T, jnp.int32),
-        catch_pc=jnp.zeros(T, jnp.int32),
-        catch_rsp=jnp.zeros(T, jnp.int32),
-        pending_exc=jnp.zeros(T, jnp.int32),
-        last_exc=jnp.zeros(T, jnp.int32),
-        io_op=jnp.zeros(T, jnp.int32),
-        handlers=jnp.zeros(NUM_EXC, jnp.int32),
-        cur=jnp.int32(0),
-        now=jnp.int32(0),
-        steps=jnp.int32(0),
-        rng=jnp.uint32(seed),
-        out=jnp.zeros(cfg.out_ring_size * 2, jnp.int32),
-        outp=jnp.int32(0),
-        mbox=jnp.zeros(cfg.mbox_size * 2, jnp.int32),
-        mbox_rd=jnp.int32(0),
-        mbox_wr=jnp.int32(0),
+        cs=np.zeros(cfg.cs_size, np.int32),
+        mem=np.zeros(cfg.mem_size, np.int32),
+        ds=np.zeros((T, cfg.ds_size), np.int32),
+        rs=np.zeros((T, cfg.rs_size), np.int32),
+        fs=np.zeros((T, cfg.fs_size), np.int32),
+        dsp=np.zeros(T, np.int32),
+        rsp=np.zeros(T, np.int32),
+        fsp=np.zeros(T, np.int32),
+        pc=np.zeros(T, np.int32),
+        tstatus=np.full(T, ST_FREE, np.int32),
+        prio=np.zeros(T, np.int32),
+        deadline=np.zeros(T, np.int32),
+        timeout=np.zeros(T, np.int32),
+        ev_addr=np.zeros(T, np.int32),
+        ev_val=np.zeros(T, np.int32),
+        catch_pc=np.zeros(T, np.int32),
+        catch_rsp=np.zeros(T, np.int32),
+        pending_exc=np.zeros(T, np.int32),
+        last_exc=np.zeros(T, np.int32),
+        io_op=np.zeros(T, np.int32),
+        handlers=np.zeros(NUM_EXC, np.int32),
+        cur=np.array(0, np.int32),
+        now=np.array(0, np.int32),
+        steps=np.array(0, np.int32),
+        rng=np.array(seed, np.uint32),
+        out=np.zeros(cfg.out_ring_size * 2, np.int32),
+        outp=np.array(0, np.int32),
+        mbox=np.zeros(cfg.mbox_size * 2, np.int32),
+        mbox_rd=np.array(0, np.int32),
+        mbox_wr=np.array(0, np.int32),
     )
 
 

@@ -12,30 +12,19 @@ Subsystems:
   rwkv6_scan — RWKV6 chunked WKV scan
   vmloop     — the VM fleet's inner interpreter loop: an on-chip
                fetch/dispatch/stack engine (one grid program per node,
-               per-node machine state in VMEM, flat lax.switch branch
-               table), byte-exact vs the lax interpreter/Oracle over its
-               claimed opcode set and bailing to the lax tail otherwise.
+               the node's machine state DMA'd into scalar memory, a
+               branch table over the opcode space), byte-exact vs the
+               lax interpreter/Oracle over its claimed opcode set and
+               bailing to the lax tail otherwise.
                Selected per fleet via ``FleetVM(executor="pallas")`` /
                ``REXAVM(backend="pallas")`` rather than ``use_kernels()``.
 
-On this CPU container kernels run under interpret=True; models select the
-kernel vs jnp path via ``repro.kernels.use_kernels()``.
+On a CPU backend kernels run under interpret=True (tests); on a TPU they
+run compiled and interpret mode is refused (``check_interpret``).  Models
+select the kernel vs jnp path via ``repro.kernels.use_kernels()``.
 """
 
 import jax
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams across versions;
-# resolve whichever this jax ships so every kernel builds on either side.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or getattr(
-    _pltpu, "TPUCompilerParams"
-)
-
-
-def tpu_compiler_params(**kwargs):
-    """Version-portable constructor for Pallas TPU compiler params."""
-    return CompilerParams(**kwargs)
-
 
 _FORCE = None  # None = auto (TPU only), True/False = override
 
@@ -57,4 +46,15 @@ def use_kernels():
 
 def interpret_mode() -> bool:
     """True only when kernels are forced into interpret mode (CPU testing)."""
-    return _FORCE == "interpret"
+    return check_interpret(_FORCE == "interpret")
+
+
+def check_interpret(interpret: bool) -> bool:
+    """Pass ``interpret`` through, refusing it on a TPU backend: there the
+    kernels run compiled, never in the Pallas interpreter."""
+    if interpret and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "Pallas interpret mode requested on a TPU backend; kernels run "
+            "compiled there (set_kernels('off'/'interpret') is for CPU tests)"
+        )
+    return interpret

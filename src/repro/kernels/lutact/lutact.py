@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 from repro.core.fixedpoint.luts import _SIG_INTERP_LUT, _SIG_INTERP_MAX, _SIG_INTERP_N
 
 _STEP = _SIG_INTERP_MAX // _SIG_INTERP_N  # 250
@@ -75,7 +74,7 @@ def lut_sigmoid(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

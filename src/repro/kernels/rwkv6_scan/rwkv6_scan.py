@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 
 def _kernel(
@@ -117,7 +116,7 @@ def rwkv6_scan(
             jax.ShapeDtypeStruct((B, H, K, K), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

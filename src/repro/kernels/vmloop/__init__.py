@@ -3,21 +3,22 @@ interpreter loop.
 
 Three-file convention (see ``repro.kernels``):
   vmloop.py — ``pl.pallas_call`` kernel: grid ``(nodes_per_shard,)``, one
-              node's full machine state in VMEM, ``steps`` on-chip
-              fetch/decode/execute iterations over a flat branch table;
+              node's machine state (a flat int32 row) DMA'd into SMEM,
+              ``steps`` on-chip fetch/decode/execute iterations over a
+              branch table;
   ops.py    — ``fleet_vmloop``: stacked-VMState wrapper with node-mesh
-              ``shard_map`` and the interpret switch;
-  ref.py    — shared step semantics + ``vmloop_ref``, the pure-jnp oracle
-              (also defines the SUPPORTED/BAILOUT opcode claim).
+              ``jax.shard_map`` and the interpret switch;
+  ref.py    — the kernel's step semantics (also defines the
+              SUPPORTED/BAILOUT opcode claim).
 
 Claimed vs declined (``ref.SUPPORTED_WORDS`` / ``ref.BAILOUT_WORDS``): the
 kernel now claims essentially the whole ISA — stack/arith/cmp/bit/mem/ctl/
 exc words, printing into the out ring, the IO-suspending words
 (``send``/``receive``/``out``/``in`` execute their suspension in-kernel;
 delivery stays with the host service and the collective router), the LUT
-fixed-point DSP scalars (VMEM table gathers), and the vector/ANN ops
-(``vecfold``/``dotprod`` contract on the MXU via ``lax.dot_general`` at
-int32; ``lowp``/``highp``/``hull`` are short on-chip IIR scans).  Only
+fixed-point DSP scalars (table loads), and the vector/ANN ops
+(``vecfold``/``dotprod`` accumulate int32 products element by element;
+``lowp``/``highp``/``hull`` are short on-chip IIR scans).  Only
 ``task`` spawn, ``rnd``, and FIOS host calls still bail to the lax tail —
 ``FleetVM.pallas_stats()`` reports the split plus a per-opcode bail
 histogram.
@@ -49,12 +50,10 @@ from repro.kernels.vmloop.ref import (
     SUPPORTED_WORDS,
     CoreState,
     supported_mask,
-    vmloop_ref,
 )
 
 __all__ = [
     "fleet_vmloop",
-    "vmloop_ref",
     "CoreState",
     "SUPPORTED_WORDS",
     "BAILOUT_WORDS",

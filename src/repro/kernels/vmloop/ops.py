@@ -25,13 +25,14 @@ the direct path.
 
 from __future__ import annotations
 
+import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.config import VMConfig
 from repro.core.vm.spec import ISA
 from repro.core.vm.vmstate import VMState
-from repro.kernels.vmloop.ref import core_of, merge_core, vmloop_ref
+from repro.kernels.vmloop.ref import core_of, merge_core
 from repro.kernels.vmloop.vmloop import vmloop_call
 
 
@@ -62,10 +63,8 @@ def fleet_vmloop(
     if mesh is not None:
         ndev = int(np.prod(mesh.devices.shape))
         if ndev > 1 and N % ndev == 0:
-            from jax.experimental.shard_map import shard_map
-
             ax = mesh.axis_names[0]
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 lambda c: vmloop_call(
                     c, steps, cfg, isa, interpret=interpret, obs=obs,
                     elide_checks=elide_checks,
@@ -73,7 +72,7 @@ def fleet_vmloop(
                 mesh=mesh,
                 in_specs=(P(ax),),
                 out_specs=(P(ax),) * n_out,
-                check_rep=False,
+                check_vma=False,
             )
             core, *rest = sharded(core)
             return (merge_core(S, core), *rest)
@@ -84,4 +83,4 @@ def fleet_vmloop(
     return (merge_core(S, core), *rest)
 
 
-__all__ = ["fleet_vmloop", "vmloop_ref"]
+__all__ = ["fleet_vmloop"]

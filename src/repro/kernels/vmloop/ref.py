@@ -1,16 +1,23 @@
-"""Shared step semantics + pure-jnp oracle for the vmloop Pallas kernel.
+"""Step semantics of the vmloop Pallas kernel.
 
 The kernel's contract is *byte-exactness* with the lax interpreter
 (``repro.core.vm.interp``) and the Python ``Oracle`` over the opcode subset
 it claims — the paper's software/hardware operational-equivalence claim
 restated for a TPU kernel backend.  The kernel-side fetch/decode/execute
-step is written once here, in pure jnp over a reduced :class:`CoreState`
-(the VMState fields the claimed opcodes can touch), and used by both
+step is written once here, over one node's machine state held in a flat
+int32 *row* (:class:`Layout`) that it reads and writes through a ref, and
+run by ``vmloop.vmloop_call``, the ``pl.pallas_call`` kernel, with the
+node's row DMA'd into scalar memory (SMEM).
 
-  * :func:`vmloop_ref`          — the pure-jnp oracle (vmapped over nodes),
-    the reference the allclose/byte-exact sweeps in tests compare against;
-  * ``vmloop.vmloop_call``      — the ``pl.pallas_call`` kernel, which runs
-    the very same ``run_core`` loop with the node's state held in VMEM.
+Every state access is a scalar load or store at a computed offset of the
+row, so the step lowers on a TPU: Mosaic has no value-level
+``dynamic_slice``/gather/scatter, but scalar memory takes dynamic
+addresses.  Reads clamp each index into its field (jnp's gather rule);
+writes outside a field (the ``mode="drop"`` sites of ``interp.py``) land
+on a trash cell past the state.  Vector words loop over their window one
+element at a time, computing into a scratch window before any write, so
+overlapping source and destination windows behave as the lax
+interpreter's whole-vector reads do.
 
 Relative to ``interp.py`` this is a deliberate *independent transliteration*
 of the step semantics, exactly as ``oracle.py`` is for plain Python: the
@@ -31,30 +38,28 @@ exception machinery, printing into the out ring, the IO-suspending words
 pc rewind + ``io_op`` + ST_IOWAIT, then the loop exits on the status change
 with ``bailed`` false — delivery stays with the host service loop and the
 collective router between kernel invocations), the LUT fixed-point DSP
-scalars (``sin``/``log``/``sigmoid``/``relu``/``sqrt`` as VMEM table
-gathers; the tables ride as kernel operands), and the vector/ANN ops
-(``vecfold``/``dotprod`` lower onto the MXU via ``lax.dot_general`` with an
-int32 accumulator — the ``fixmatmul`` idiom, byte-exact because int32
-wraparound arithmetic is order-independent; ``lowp``/``highp``/``hull`` are
-the short on-chip IIR scan of ``rwkv6_scan`` shape).
+scalars (``sin``/``log``/``sigmoid``/``relu``/``sqrt`` as table loads; the
+tables ride as a kernel operand), and the vector/ANN ops
+(``vecfold``/``dotprod`` accumulate int32 products element by element —
+byte-exact with ``interp``'s sum of products because int32 wraparound
+addition is order-independent; ``lowp``/``highp``/``hull`` are a short
+IIR scan).
 
 ``BAILOUT_WORDS`` is down to ``task`` (spawning writes prio/deadline and
 arbitrary task slots outside CoreState) and ``rnd`` (the LCG state is
-uint32 while every kernel block is int32).  On the first declined (or
-unknown/FIOS) opcode the loop *bails out before executing it*, reporting
-how many instructions it did run plus *which opcode* bailed (``bail_op``,
-feeding the per-opcode bail histogram in ``pallas_stats()``), so the
-host-side lax path can finish the slice from a byte-identical intermediate
-state.  Every ISA word MUST appear in exactly one of the two sets —
-``supported_mask`` raises otherwise, and the ISA coverage test sweeps the
-claim.
+uint32 while the row is int32).  On the first declined (or unknown/FIOS)
+opcode the loop *bails out before executing it*, reporting how many
+instructions it did run plus *which opcode* bailed (``bail_op``, feeding
+the per-opcode bail histogram in ``pallas_stats()``), so the host-side lax
+path can finish the slice from a byte-identical intermediate state.  Every
+ISA word MUST appear in exactly one of the two sets — ``supported_mask``
+raises otherwise, and the ISA coverage test sweeps the claim.
 """
 
 from __future__ import annotations
 
 from typing import Callable, NamedTuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -110,12 +115,12 @@ MUTATED_FIELDS = tuple(f for f in CORE_FIELDS if f not in READONLY_FIELDS)
 
 
 class Tables(NamedTuple):
-    """Constant dispatch + LUT tables, passed as explicit kernel operands (a
+    """Constant dispatch + LUT tables, passed as one flat kernel operand (a
     Pallas kernel cannot close over array constants).  All int32.  The five
     ``(num_ops + 1,)`` dispatch tables: ``sup`` is the opcode claim mask
     (0/1), the rest are the stack-effect pre-check of ``interp.exec_op``.
-    The four fixed-point LUTs back the DSP scalar words as VMEM gathers:
-    ``log10`` (90,), ``sg13`` (24,), ``sg310`` (6,), ``sinq`` (256,)."""
+    The four fixed-point LUTs back the DSP scalar words: ``log10`` (90,),
+    ``sg13`` (24,), ``sg310`` (6,), ``sinq`` (256,)."""
 
     sup: jnp.ndarray
     din: jnp.ndarray
@@ -195,7 +200,7 @@ SUPPORTED_WORDS = (
 
 BAILOUT_WORDS = (
     # task spawn writes prio/deadline + arbitrary task slots (outside
-    # CoreState); rnd advances the uint32 LCG (kernel blocks are int32).
+    # CoreState); rnd advances the uint32 LCG (the row is int32).
     "task", "rnd",
 )
 
@@ -245,6 +250,17 @@ def make_tables(isa: ISA | None = None) -> Tables:
     )
 
 
+def flat_tables(isa: ISA | None = None) -> tuple[np.ndarray, dict]:
+    """All of :func:`make_tables` concatenated into one int32 vector, plus
+    each table's ``(offset, length)`` in it."""
+    tables = make_tables(isa)
+    spans, pos = {}, 0
+    for name, t in zip(Tables._fields, tables):
+        spans[name] = (pos, len(t))
+        pos += len(t)
+    return np.concatenate(list(tables)).astype(np.int32), spans
+
+
 # --- VMState <-> CoreState ---------------------------------------------------
 
 def core_of(S: VMState) -> CoreState:
@@ -257,61 +273,203 @@ def merge_core(S: VMState, core: CoreState) -> VMState:
     return S._replace(**{f: getattr(core, f) for f in MUTATED_FIELDS})
 
 
+# --- one node's flat row -----------------------------------------------------
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+class Layout:
+    """Where each :class:`CoreState` field lives in a node's flat int32 row.
+
+    The CoreState fields come first, in ``CORE_FIELDS`` order and row-major
+    each.  After them: the loop's results (``n_exec``, ``bailed``,
+    ``bail_op``), the obs retirement histogram (``num_ops + 4`` bins), a
+    ``max_vec`` scratch window for the vector words, and the trash cell
+    that dropped writes land on.  The width is padded to whole 128-lane
+    rows."""
+
+    def __init__(self, cfg: VMConfig, isa: ISA | None = None):
+        isa = isa or get_isa()
+        T = cfg.max_tasks
+        per_task = (T,)
+        self.shape: dict[str, tuple[int, ...]] = {
+            "cs": (cfg.cs_size,), "mem": (cfg.mem_size,),
+            "ds": (T, cfg.ds_size), "rs": (T, cfg.rs_size),
+            "fs": (T, cfg.fs_size),
+            "handlers": (NUM_EXC,), "out": (2 * cfg.out_ring_size,),
+            **{f: () for f in SCALAR_FIELDS},
+        }
+        self.off: dict[str, int] = {}
+        pos = 0
+        for f in CORE_FIELDS:
+            shp = self.shape.setdefault(f, per_task)
+            self.off[f] = pos
+            pos += int(np.prod(shp, dtype=np.int64))
+        self.core_words = pos
+        self.n_exec, self.bailed, self.bail_op = pos, pos + 1, pos + 2
+        self.hist = pos + 3
+        self.nbins = isa.num_ops + 4
+        self.tmp = self.hist + self.nbins
+        self.trash = self.tmp + cfg.max_vec
+        self.width = _round_up(self.trash + 1, 128)
+
+    def pack(self, core: CoreState) -> jnp.ndarray:
+        """Stacked CoreState (node-leading) -> ``(N, 1, width)`` rows."""
+        N = core.pc.shape[0]
+        cols = [getattr(core, f).reshape(N, -1) for f in CORE_FIELDS]
+        cols.append(jnp.zeros((N, self.width - self.core_words), I32))
+        return jnp.concatenate(cols, axis=1).reshape(N, 1, self.width)
+
+    def unpack(self, rows: jnp.ndarray):
+        """``(N, 1, width)`` rows -> ``(core, n_exec, bailed, bail_op,
+        op_hist)``, each node-leading."""
+        N = rows.shape[0]
+        flat = rows.reshape(N, self.width)
+
+        def cut(off, shp):
+            size = int(np.prod(shp, dtype=np.int64))
+            return flat[:, off:off + size].reshape((N,) + shp)
+
+        core = CoreState(
+            **{f: cut(self.off[f], self.shape[f]) for f in CORE_FIELDS}
+        )
+        return (
+            core,
+            flat[:, self.n_exec],
+            flat[:, self.bailed] != 0,
+            flat[:, self.bail_op],
+            cut(self.hist, (self.nbins,)),
+        )
+
+
+class _Node:
+    """One node's CoreState seen through its row ref ``(1, width)`` and the
+    flat table ref.  ``get`` clamps each index into its field; ``set``
+    drops writes outside the field (or with ``when`` false) onto the trash
+    cell.  ``t`` is the current task (``cur`` is read-only in the loop)."""
+
+    def __init__(self, lay: Layout, row, tab, spans: dict):
+        self.lay, self.row, self.tab_ref, self.spans = lay, row, tab, spans
+        self.t = self.get("cur")
+
+    def _lin(self, f, idx, clamp):
+        lin, ok = I32(0), jnp.bool_(True)
+        for i, n in zip(idx, self.lay.shape[f]):
+            i = jnp.asarray(i, I32)
+            if clamp:
+                i = jnp.clip(i, 0, n - 1)
+            else:
+                ok = ok & (i >= 0) & (i < n)
+            lin = lin * n + i
+        return self.lay.off[f] + lin, ok
+
+    def get(self, f, *idx):
+        flat, _ = self._lin(f, idx, clamp=True)
+        return self.row[0, flat]
+
+    def set(self, f, v, *idx, when=None):
+        flat, ok = self._lin(f, idx, clamp=False)
+        if when is not None:
+            ok = ok & when
+        self.row[0, jnp.where(ok, flat, self.lay.trash)] = jnp.asarray(v, I32)
+
+    def cell(self, k):
+        return self.row[0, k]
+
+    def set_cell(self, k, v):
+        self.row[0, k] = jnp.asarray(v, I32)
+
+    def tab(self, name, i):
+        off, n = self.spans[name]
+        return self.tab_ref[off + jnp.clip(i, 0, n - 1)]
+
+
+def _loop(n, body):
+    """``body(k)`` for k in [0, n), n traced or static."""
+    def step(k, carry):
+        body(k)
+        return carry
+    lax.fori_loop(0, n, step, I32(0))
+
+
+def _tree_switch(index, branches):
+    """``lax.switch`` over zero-operand branches as a balanced tree of
+    two-way conds: Mosaic lowers an n-way switch to an n-deep if/else
+    cascade, which its canonicalizer cannot take at the ISA's width."""
+    if len(branches) == 1:
+        return branches[0]()
+    mid = len(branches) // 2
+    return lax.cond(
+        index < mid,
+        lambda: _tree_switch(index, branches[:mid]),
+        lambda: _tree_switch(index - mid, branches[mid:]),
+    )
+
+
 # --- LUT fixed-point scalars (mirror fixedpoint.luts *_jnp, but read the
 # --- tables from the kernel operand instead of module-level constants) -------
 
-def _fplog10_t(x, tb: Tables):
+def _fplog10_t(x, m: _Node):
     x = jnp.maximum(x.astype(I32), 10)
     shift = jnp.zeros_like(x)
     for _ in range(3):
         big = x >= 100
         shift = shift + big.astype(I32)
         x = jnp.where(big, x // 10, x)
-    return shift * 100 + tb.log10[jnp.clip(x - 10, 0, 89)]
+    return shift * 100 + m.tab("log10", x - 10)
 
 
-def _fpsigmoid_t(x, tb: Tables):
+def _fpsigmoid_t(x, m: _Node):
     x = x.astype(I32)
     mirror = x < 0
     ax = jnp.abs(x)
     y1 = 500 + (ax * 231) // 1000
-    i13 = jnp.clip(_fplog10_t(ax // 5, tb) // 2 - 65, 0, 23)
-    y2 = tb.sg13[i13] + 731
-    i310 = jnp.clip(_fplog10_t(ax // 10, tb) // 10 - 14, 0, 5)
-    y3 = tb.sg310[i310] + 952
+    i13 = jnp.clip(_fplog10_t(ax // 5, m) // 2 - 65, 0, 23)
+    y2 = m.tab("sg13", i13) + 731
+    i310 = jnp.clip(_fplog10_t(ax // 10, m) // 10 - 14, 0, 5)
+    y3 = m.tab("sg310", i310) + 952
     y = jnp.where(ax <= 1000, y1, jnp.where(ax < 3000, y2, y3))
     y = jnp.where(ax >= 10000, 1000, y)
     return jnp.where(mirror, 1000 - y, y)
 
 
-def _fpsin_t(x, tb: Tables):
+def _fpsin_t(x, m: _Node):
     x = jnp.mod(x.astype(I32), _TWO_PI_MR)
     x = jnp.where(x < 0, x + _TWO_PI_MR, x)
     t = x * 1024 // _TWO_PI_MR
     quad = t // 256
     idx = t % 256
-    up = tb.sinq[idx]
-    down = tb.sinq[255 - idx]
+    up = m.tab("sinq", idx)
+    down = m.tab("sinq", 255 - idx)
     mag = jnp.where((quad % 2) == 0, up, down)
     return jnp.where(quad >= 2, -mag, mag)
 
 
-# --- the step function (mirrors interp.step_instr over CoreState) ------------
+# --- the step function (mirrors interp.step_instr over one node's row) -------
+
+class CoreStep(NamedTuple):
+    """The step functions over a :class:`_Node` (see make_core_step)."""
+
+    step: Callable        # m -> None: execute one instruction in place
+    supported: Callable   # m -> bool: the next instruction may run here
+    retire_bin: Callable  # m -> int32: its obs histogram bin
+    fetch: Callable       # m -> (pc, pc_ok, tag, payload)
+
 
 def make_core_step(
     cfg: VMConfig, isa: ISA | None = None, elide_checks: bool = False
-):
-    """Returns ``(step_instr, instr_supported)`` over :class:`CoreState`.
+) -> CoreStep:
+    """Returns the :class:`CoreStep` over a :class:`_Node`.
 
-    ``step_instr`` is a transliteration of
-    :meth:`repro.core.vm.interp.Interpreter._build`'s step for the supported
-    subset — same helpers, same clip patterns, same exception dispatch — so
-    a supported instruction produces bit-identical state on either engine.
-    ``instr_supported`` is the bail predicate, evaluated on the *fetched*
-    instruction before any state is touched.  Branches take ``(st, tb)``;
-    the DSP words gather from the LUT operands in ``tb``.
+    ``step_instr(m)`` executes one instruction in place; it is a
+    transliteration of :meth:`repro.core.vm.interp.Interpreter._build`'s
+    step for the supported subset — same helpers, same clip patterns, same
+    exception dispatch — so a supported instruction produces bit-identical
+    state on either engine.  ``instr_supported(m)`` is the bail predicate,
+    evaluated on the *fetched* instruction before any state is touched.
 
-    ``elide_checks=True`` drops the LUT-driven stack pre-check and the
+    ``elide_checks=True`` drops the table-driven stack pre-check and the
     TAG_LIT push-overflow check at build time (the flag is static, so the
     check computation vanishes from the kernel, not just its outcome) —
     only sound for programs the static verifier proved EXC_STACK-free,
@@ -325,121 +483,91 @@ def make_core_step(
 
     # -- low-level helpers (identical to interp._build) ----------------------
 
-    def dpeek(st, k=1):
-        t = st.cur
-        return st.ds[t, jnp.maximum(st.dsp[t] - k, 0)]
+    def dpeek(m, k=1):
+        return m.get("ds", m.t, jnp.maximum(m.get("dsp", m.t) - k, 0))
 
-    def dpop1(st):
-        t = st.cur
-        v = st.ds[t, jnp.maximum(st.dsp[t] - 1, 0)]
-        return st._replace(dsp=st.dsp.at[t].add(-1)), v
+    def dpop1(m):
+        return dpopn(m, 1)[0]
 
-    def dpopn(st, n):
-        t = st.cur
+    def dpopn(m, n):
+        dsp = m.get("dsp", m.t)
         vals = tuple(
-            st.ds[t, jnp.maximum(st.dsp[t] - n + k, 0)] for k in range(n)
+            m.get("ds", m.t, jnp.maximum(dsp - n + k, 0)) for k in range(n)
         )
-        return st._replace(dsp=st.dsp.at[t].add(-n)), vals
+        m.set("dsp", dsp - n, m.t)
+        return vals
 
-    def dpush(st, v):
-        t = st.cur
-        return st._replace(
-            ds=st.ds.at[t, jnp.clip(st.dsp[t], 0, DS - 1)].set(
-                v.astype(I32) if hasattr(v, "astype") else I32(v)
-            ),
-            dsp=st.dsp.at[t].add(1),
-        )
+    def dpush(m, v):
+        dsp = m.get("dsp", m.t)
+        m.set("ds", v, m.t, jnp.clip(dsp, 0, DS - 1))
+        m.set("dsp", dsp + 1, m.t)
 
-    def fpush(st, v):
-        t = st.cur
-        return st._replace(
-            fs=st.fs.at[t, jnp.clip(st.fsp[t], 0, FS - 1)].set(v),
-            fsp=st.fsp.at[t].add(1),
-        )
+    def fpush(m, v):
+        fsp = m.get("fsp", m.t)
+        m.set("fs", v, m.t, jnp.clip(fsp, 0, FS - 1))
+        m.set("fsp", fsp + 1, m.t)
 
-    def set_pc(st, pc):
-        return st._replace(pc=st.pc.at[st.cur].set(pc.astype(I32)))
+    def set_pc(m, pc, when=None):
+        m.set("pc", pc, m.t, when=when)
 
-    def cur_pc(st):
-        return st.pc[st.cur]
+    def cur_pc(m):
+        return m.get("pc", m.t)
 
-    def raise_exc(st, code):
-        t = st.cur
-        return st._replace(
-            pending_exc=st.pending_exc.at[t].set(
-                jnp.where(st.pending_exc[t] == 0, code, st.pending_exc[t])
-            )
-        )
+    def raise_exc(m, code, when=None):
+        p = m.get("pending_exc", m.t)
+        m.set("pending_exc", jnp.where(p == 0, code, p), m.t, when=when)
 
-    def set_status(st, s):
-        return st._replace(tstatus=st.tstatus.at[st.cur].set(s))
+    def set_status(m, s, when=None):
+        m.set("tstatus", s, m.t, when=when)
 
     def addr_valid(addr):
         in_cs = (addr >= 0) & (addr < CS)
         in_mem = (addr >= MEM_BASE) & (addr < MEM_BASE + MEM)
         return in_cs | in_mem
 
-    def mread(st, addr):
-        in_mem = addr >= MEM_BASE
-        cs_v = st.cs[jnp.clip(addr, 0, CS - 1)]
-        mem_v = st.mem[jnp.clip(addr - MEM_BASE, 0, MEM - 1)]
-        return jnp.where(in_mem, mem_v, cs_v)
+    def mread(m, addr):
+        cs_v = m.get("cs", jnp.clip(addr, 0, CS - 1))
+        mem_v = m.get("mem", jnp.clip(addr - MEM_BASE, 0, MEM - 1))
+        return jnp.where(addr >= MEM_BASE, mem_v, cs_v)
 
-    def mwrite(st, addr, v):
-        v = v.astype(I32)
+    def mwrite(m, addr, v, when=True):
         in_mem = addr >= MEM_BASE
-        cs_idx = jnp.where(in_mem, CS, jnp.clip(addr, 0, CS - 1))
-        mem_idx = jnp.where(in_mem, jnp.clip(addr - MEM_BASE, 0, MEM - 1), MEM)
-        return st._replace(
-            cs=st.cs.at[cs_idx].set(v, mode="drop"),
-            mem=st.mem.at[mem_idx].set(v, mode="drop"),
-        )
+        m.set("cs", v, jnp.clip(addr, 0, CS - 1), when=~in_mem & when)
+        m.set("mem", v, jnp.clip(addr - MEM_BASE, 0, MEM - 1), when=in_mem & when)
 
-    def vread(st, addr, window, length=None):
-        """Gather ``window`` cells from addr; mask beyond header length."""
-        ln = mread(st, addr - 1) if length is None else length
-        ln = jnp.clip(ln, 0, window)
-        idx = addr + jnp.arange(window, dtype=I32)
+    # A vector operand at ``addr`` lives wholly in CS or wholly in DIOS,
+    # chosen by its base address; element k reads/writes addr + k clipped
+    # into that memory (interp's vread/vwrite, one lane at a time).
+
+    def velem(m, addr, k):
+        idx = addr + k
+        cs_v = m.get("cs", jnp.clip(idx, 0, CS - 1))
+        mem_v = m.get("mem", jnp.clip(idx - MEM_BASE, 0, MEM - 1))
+        return jnp.where(addr >= MEM_BASE, mem_v, cs_v)
+
+    def vset(m, addr, k, v):
+        idx = addr + k
         in_mem = addr >= MEM_BASE
-        cs_vals = jnp.take(st.cs, jnp.clip(idx, 0, CS - 1))
-        mem_vals = jnp.take(st.mem, jnp.clip(idx - MEM_BASE, 0, MEM - 1))
-        vals = jnp.where(in_mem, mem_vals, cs_vals)
-        mask = jnp.arange(window) < ln
-        return jnp.where(mask, vals, 0), ln, mask
+        m.set("cs", v, jnp.clip(idx, 0, CS - 1), when=~in_mem)
+        m.set("mem", v, jnp.clip(idx - MEM_BASE, 0, MEM - 1), when=in_mem)
 
-    def vwrite(st, addr, vals, ln):
-        window = vals.shape[0]
-        mask = jnp.arange(window) < ln
-        in_mem = addr >= MEM_BASE
-        idx = addr + jnp.arange(window, dtype=I32)
-        cs_idx = jnp.where(mask & ~in_mem, jnp.clip(idx, 0, CS - 1), CS)
-        mem_idx = jnp.where(mask & in_mem, jnp.clip(idx - MEM_BASE, 0, MEM - 1), MEM)
-        return st._replace(
-            cs=st.cs.at[cs_idx].set(vals.astype(I32), mode="drop"),
-            mem=st.mem.at[mem_idx].set(vals.astype(I32), mode="drop"),
-        )
+    def vlen(m, addr):
+        """A vector's length from its header cell, clipped to the window."""
+        return jnp.clip(mread(m, addr - 1), 0, MV)
 
-    def out_write(st, kind, val):
-        p = st.outp
+    def vcompute(m, dst, ln, elem):
+        """dst[k] = elem(k) for k < ln: every element is computed into the
+        scratch window before any is written, as interp reads whole
+        vectors before its scatter."""
+        _loop(ln, lambda k: m.set_cell(m.lay.tmp + k, elem(k)))
+        _loop(ln, lambda k: vset(m, dst, k, m.cell(m.lay.tmp + k)))
+
+    def out_write(m, kind, val):
+        p = m.get("outp")
         ok = p < OUTN
-        idx0 = jnp.where(ok, 2 * p, 2 * OUTN)
-        return st._replace(
-            out=st.out.at[idx0].set(kind, mode="drop")
-            .at[idx0 + 1].set(val.astype(I32), mode="drop"),
-            outp=jnp.where(ok, p + 1, p),
-        )
-
-    def out_write_vec(st, vals, ln):
-        window = vals.shape[0]
-        p = st.outp
-        k = jnp.arange(window, dtype=I32)
-        mask = (k < ln) & (p + k < OUTN)
-        base = 2 * (p + k)
-        kidx = jnp.where(mask, base, 2 * OUTN)
-        vidx = jnp.where(mask, base + 1, 2 * OUTN)
-        out = st.out.at[kidx].set(OUT_NUM, mode="drop")
-        out = out.at[vidx].set(vals.astype(I32), mode="drop")
-        return st._replace(out=out, outp=jnp.minimum(p + jnp.clip(ln, 0, window), OUTN))
+        m.set("out", kind, 2 * p, when=ok)
+        m.set("out", val, 2 * p + 1, when=ok)
+        m.set("outp", jnp.where(ok, p + 1, p))
 
     # scale-vector application (paper Tab. 5 semantics) -----------------------
 
@@ -450,109 +578,96 @@ def make_core_step(
         scaled = jnp.where(svals > 0, expanded, jnp.where(svals < 0, reduced, vals))
         return jnp.where(s_on, scaled, vals)
 
-    def apply_scalevec(st, dst_vals, ln, saddr):
+    def scaled_elem(m, saddr, val_of):
+        """k -> val_of(k) scaled by the optional scale vector at saddr."""
         s_on = saddr != 0
-        svals, _, _ = vread(st, jnp.where(s_on, saddr, I32(1)), MV, length=ln)
-        return vscale(dst_vals, svals, s_on)
+        sbase = jnp.where(s_on, saddr, I32(1))
+        return lambda k: vscale(val_of(k), velem(m, sbase, k), s_on)
 
-    # -- opcode implementations ----------------------------------------------
+    # -- opcode implementations (each mutates the node in place) -------------
 
     def bin_op(f):
-        def op(st):
-            st, (a, b) = dpopn(st, 2)
-            return dpush(st, f(a, b))
+        def op(m):
+            a, b = dpopn(m, 2)
+            dpush(m, f(a, b))
         return op
 
     def un_op(f):
-        def op(st):
-            st, v = dpop1(st)
-            return dpush(st, f(v))
+        def op(m):
+            dpush(m, f(dpop1(m)))
         return op
 
     def un_op_t(f):
-        def op(st, tb):
-            st, v = dpop1(st)
-            return dpush(st, f(v, tb))
+        def op(m):
+            dpush(m, f(dpop1(m), m))
         return op
 
     def cmp_op(f):
         return bin_op(lambda a, b: jnp.where(f(a, b), I32(-1), I32(0)))
 
-    B: dict[str, Callable] = {}       # st-only bodies
-    TB: dict[str, Callable] = {}      # (st, tb) bodies — LUT gathers
+    B: dict[str, Callable] = {}
 
-    B["nop"] = lambda st: st
-    B["dup"] = lambda st: dpush(st, dpeek(st))
+    B["nop"] = lambda m: None
+    B["dup"] = lambda m: dpush(m, dpeek(m))
+    B["drop"] = dpop1
 
-    def op_drop(st):
-        st, _ = dpop1(st)
-        return st
-    B["drop"] = op_drop
-
-    def op_swap(st):
-        st, (a, b) = dpopn(st, 2)
-        return dpush(dpush(st, b), a)
+    def op_swap(m):
+        a, b = dpopn(m, 2)
+        dpush(m, b)
+        dpush(m, a)
     B["swap"] = op_swap
 
-    B["over"] = lambda st: dpush(st, dpeek(st, 2))
+    B["over"] = lambda m: dpush(m, dpeek(m, 2))
 
-    def op_rot(st):
-        st, (a, b, c) = dpopn(st, 3)
-        return dpush(dpush(dpush(st, b), c), a)
+    def op_rot(m):
+        a, b, c = dpopn(m, 3)
+        for v in (b, c, a):
+            dpush(m, v)
     B["rot"] = op_rot
 
-    def op_nip(st):
-        st, (a, b) = dpopn(st, 2)
-        return dpush(st, b)
+    def op_nip(m):
+        _, b = dpopn(m, 2)
+        dpush(m, b)
     B["nip"] = op_nip
 
-    def op_tuck(st):
-        st, (a, b) = dpopn(st, 2)
-        return dpush(dpush(dpush(st, b), a), b)
+    def op_tuck(m):
+        a, b = dpopn(m, 2)
+        for v in (b, a, b):
+            dpush(m, v)
     B["tuck"] = op_tuck
 
-    def op_pick(st):
-        st, n = dpop1(st)
-        t = st.cur
-        idx = jnp.clip(st.dsp[t] - 1 - n, 0, DS - 1)
-        bad = (n < 0) | (n >= st.dsp[t])
-        st = dpush(st, st.ds[t, idx])
-        return lax.cond(bad, lambda s: raise_exc(s, EXC_STACK), lambda s: s, st)
+    def op_pick(m):
+        n = dpop1(m)
+        dsp = m.get("dsp", m.t)
+        idx = jnp.clip(dsp - 1 - n, 0, DS - 1)
+        bad = (n < 0) | (n >= dsp)
+        dpush(m, m.get("ds", m.t, idx))
+        raise_exc(m, EXC_STACK, when=bad)
     B["pick"] = op_pick
 
-    def op_2dup(st):
-        a, b = dpeek(st, 2), dpeek(st, 1)
-        return dpush(dpush(st, a), b)
+    def op_2dup(m):
+        a, b = dpeek(m, 2), dpeek(m, 1)
+        dpush(m, a)
+        dpush(m, b)
     B["2dup"] = op_2dup
 
-    def op_2drop(st):
-        st, _ = dpopn(st, 2)
-        return st
-    B["2drop"] = op_2drop
-
-    B["depth"] = lambda st: dpush(st, st.dsp[st.cur])
+    B["2drop"] = lambda m: dpopn(m, 2)
+    B["depth"] = lambda m: dpush(m, m.get("dsp", m.t))
 
     B["+"] = bin_op(lambda a, b: a + b)
     B["-"] = bin_op(lambda a, b: a - b)
     B["*"] = bin_op(lambda a, b: a * b)
 
-    def op_div(st):
-        st, (a, b) = dpopn(st, 2)
-        st = dpush(st, _truncdiv(a, b))
-        return lax.cond(b == 0, lambda s: raise_exc(s, EXC_DIVBYZERO), lambda s: s, st)
-    B["/"] = op_div
+    def div_like(nargs, f):
+        def op(m):
+            args = dpopn(m, nargs)
+            dpush(m, f(*args))
+            raise_exc(m, EXC_DIVBYZERO, when=args[-1] == 0)
+        return op
 
-    def op_mod(st):
-        st, (a, b) = dpopn(st, 2)
-        st = dpush(st, _truncmod(a, b))
-        return lax.cond(b == 0, lambda s: raise_exc(s, EXC_DIVBYZERO), lambda s: s, st)
-    B["mod"] = op_mod
-
-    def op_muldiv(st):
-        st, (a, b, c) = dpopn(st, 3)
-        st = dpush(st, _muldiv(a, b, c))
-        return lax.cond(c == 0, lambda s: raise_exc(s, EXC_DIVBYZERO), lambda s: s, st)
-    B["*/"] = op_muldiv
+    B["/"] = div_like(2, _truncdiv)
+    B["mod"] = div_like(2, _truncmod)
+    B["*/"] = div_like(3, _muldiv)
 
     B["negate"] = un_op(lambda v: -v)
     B["abs"] = un_op(jnp.abs)
@@ -580,201 +695,177 @@ def make_core_step(
     B["lshift"] = bin_op(lambda a, n: a << (n & 31))
     B["rshift"] = bin_op(lambda a, n: a >> (n & 31))
 
-    def op_fetch(st):
-        st, addr = dpop1(st)
-        st = dpush(st, mread(st, addr))
-        return lax.cond(addr_valid(addr), lambda s: s, lambda s: raise_exc(s, EXC_BOUNDS), st)
+    def op_fetch(m):
+        addr = dpop1(m)
+        dpush(m, mread(m, addr))
+        raise_exc(m, EXC_BOUNDS, when=~addr_valid(addr))
     B["@"] = op_fetch
 
-    def op_store(st):
-        st, (v, addr) = dpopn(st, 2)
-        st = mwrite(st, addr, v)
-        return lax.cond(addr_valid(addr), lambda s: s, lambda s: raise_exc(s, EXC_BOUNDS), st)
+    def op_store(m):
+        v, addr = dpopn(m, 2)
+        mwrite(m, addr, v)
+        raise_exc(m, EXC_BOUNDS, when=~addr_valid(addr))
     B["!"] = op_store
 
-    def op_addstore(st):
-        st, (v, addr) = dpopn(st, 2)
-        st = mwrite(st, addr, mread(st, addr) + v)
-        return lax.cond(addr_valid(addr), lambda s: s, lambda s: raise_exc(s, EXC_BOUNDS), st)
+    def op_addstore(m):
+        v, addr = dpopn(m, 2)
+        mwrite(m, addr, mread(m, addr) + v)
+        raise_exc(m, EXC_BOUNDS, when=~addr_valid(addr))
     B["+!"] = op_addstore
 
-    def op_get(st):
-        st, (n, arr) = dpopn(st, 2)
-        ln = mread(st, arr - 1)
+    def op_get(m):
+        n, arr = dpopn(m, 2)
+        ln = mread(m, arr - 1)
         bad = (n < 0) | (n >= ln)
-        st = dpush(st, mread(st, arr + jnp.clip(n, 0, jnp.maximum(ln - 1, 0))))
-        return lax.cond(bad, lambda s: raise_exc(s, EXC_BOUNDS), lambda s: s, st)
+        dpush(m, mread(m, arr + jnp.clip(n, 0, jnp.maximum(ln - 1, 0))))
+        raise_exc(m, EXC_BOUNDS, when=bad)
     B["get"] = op_get
 
-    def op_put(st):
-        st, (v, n, arr) = dpopn(st, 3)
-        ln = mread(st, arr - 1)
+    def op_put(m):
+        v, n, arr = dpopn(m, 3)
+        ln = mread(m, arr - 1)
         bad = (n < 0) | (n >= ln)
-        st = lax.cond(bad, lambda s: s, lambda s: mwrite(s, arr + n, v), st)
-        return lax.cond(bad, lambda s: raise_exc(s, EXC_BOUNDS), lambda s: s, st)
+        mwrite(m, arr + n, v, when=~bad)
+        raise_exc(m, EXC_BOUNDS, when=bad)
     B["put"] = op_put
 
-    def op_push(st):
-        st, (v, arr) = dpopn(st, 2)
-        top = mread(st, arr)
-        ln = mread(st, arr - 1)
+    def op_push(m):
+        v, arr = dpopn(m, 2)
+        top = mread(m, arr)
+        ln = mread(m, arr - 1)
         bad = top + 1 >= ln
-
-        def do(s):
-            s = mwrite(s, arr + top + 1, v)
-            return mwrite(s, arr, top + 1)
-        return lax.cond(bad, lambda s: raise_exc(s, EXC_BOUNDS), do, st)
+        raise_exc(m, EXC_BOUNDS, when=bad)
+        mwrite(m, arr + top + 1, v, when=~bad)
+        mwrite(m, arr, top + 1, when=~bad)
     B["push"] = op_push
 
-    def op_pop(st):
-        st, arr = dpop1(st)
-        top = mread(st, arr)
+    def op_pop(m):
+        arr = dpop1(m)
+        top = mread(m, arr)
         bad = top <= 0
-        v = mread(st, arr + jnp.maximum(top, 1))
-        st = dpush(st, jnp.where(bad, 0, v))
-        st = lax.cond(
-            bad,
-            lambda s: raise_exc(s, EXC_BOUNDS),
-            lambda s: mwrite(s, arr, top - 1),
-            st,
-        )
-        return st
+        v = mread(m, arr + jnp.maximum(top, 1))
+        dpush(m, jnp.where(bad, 0, v))
+        raise_exc(m, EXC_BOUNDS, when=bad)
+        mwrite(m, arr, top - 1, when=~bad)
     B["pop"] = op_pop
 
-    def op_fill(st):
-        st, (v, arr) = dpopn(st, 2)
-        _, ln, _ = vread(st, arr, MV)
-        return vwrite(st, arr, jnp.full((MV,), 0, I32) + v, ln)
+    def op_fill(m):
+        v, arr = dpopn(m, 2)
+        _loop(vlen(m, arr), lambda k: vset(m, arr, k, v))
     B["fill"] = op_fill
 
-    def op_len(st):
-        st, arr = dpop1(st)
-        return dpush(st, mread(st, arr - 1))
-    B["len"] = op_len
+    B["len"] = lambda m: dpush(m, mread(m, dpop1(m) - 1))
 
     # control ----------------------------------------------------------------
 
-    def op_branch(st):
-        tgt = st.cs[jnp.clip(cur_pc(st), 0, CS - 1)]
-        return set_pc(st, tgt)
-    B["branch"] = op_branch
+    B["branch"] = lambda m: set_pc(m, m.get("cs", jnp.clip(cur_pc(m), 0, CS - 1)))
 
-    def op_0branch(st):
-        st, f = dpop1(st)
-        pc = cur_pc(st)
-        tgt = st.cs[jnp.clip(pc, 0, CS - 1)]
-        return set_pc(st, jnp.where(f == 0, tgt, pc + 1))
+    def op_0branch(m):
+        f = dpop1(m)
+        pc = cur_pc(m)
+        tgt = m.get("cs", jnp.clip(pc, 0, CS - 1))
+        set_pc(m, jnp.where(f == 0, tgt, pc + 1))
     B["0branch"] = op_0branch
 
-    def op_ret(st):
-        t = st.cur
-        under = st.rsp[t] < 1
-        addr = st.rs[t, jnp.maximum(st.rsp[t] - 1, 0)]
-        st = st._replace(rsp=st.rsp.at[t].add(-1))
-        st = set_pc(st, addr)
-        return lax.cond(
-            under,
-            lambda s: set_status(raise_exc(s, EXC_STACK), ST_ERR),
-            lambda s: s,
-            st,
-        )
+    def op_ret(m):
+        rsp = m.get("rsp", m.t)
+        under = rsp < 1
+        addr = m.get("rs", m.t, jnp.maximum(rsp - 1, 0))
+        m.set("rsp", rsp - 1, m.t)
+        set_pc(m, addr)
+        raise_exc(m, EXC_STACK, when=under)
+        set_status(m, ST_ERR, when=under)
     B["ret"] = op_ret
     B["exit"] = op_ret
 
-    def op_exec(st):
-        st, addr = dpop1(st)
-        t = st.cur
-        over = st.rsp[t] >= RS
-        st = st._replace(
-            rs=st.rs.at[t, jnp.clip(st.rsp[t], 0, RS - 1)].set(cur_pc(st)),
-            rsp=st.rsp.at[t].add(1),
-        )
-        st = set_pc(st, addr)
-        return lax.cond(over, lambda s: raise_exc(s, EXC_STACK), lambda s: s, st)
+    def op_exec(m):
+        addr = dpop1(m)
+        rsp = m.get("rsp", m.t)
+        m.set("rs", cur_pc(m), m.t, jnp.clip(rsp, 0, RS - 1))
+        m.set("rsp", rsp + 1, m.t)
+        set_pc(m, addr)
+        raise_exc(m, EXC_STACK, when=rsp >= RS)
     B["exec"] = op_exec
 
-    def op_doinit(st):
-        st, (limit, start_v) = dpopn(st, 2)
-        return fpush(fpush(st, limit), start_v)
+    def op_doinit(m):
+        limit, start_v = dpopn(m, 2)
+        fpush(m, limit)
+        fpush(m, start_v)
     B["doinit"] = op_doinit
 
-    def op_doloop(st):
-        t = st.cur
-        pc = cur_pc(st)
-        top_addr = st.cs[jnp.clip(pc, 0, CS - 1)]
-        limit = st.fs[t, jnp.maximum(st.fsp[t] - 2, 0)]
-        ctr = st.fs[t, jnp.maximum(st.fsp[t] - 1, 0)] + 1
+    def op_doloop(m):
+        pc = cur_pc(m)
+        top_addr = m.get("cs", jnp.clip(pc, 0, CS - 1))
+        fsp = m.get("fsp", m.t)
+        limit = m.get("fs", m.t, jnp.maximum(fsp - 2, 0))
+        ctr = m.get("fs", m.t, jnp.maximum(fsp - 1, 0)) + 1
         done = ctr >= limit
-        st = st._replace(
-            fs=st.fs.at[t, jnp.maximum(st.fsp[t] - 1, 0)].set(ctr),
-            fsp=st.fsp.at[t].add(jnp.where(done, -2, 0)),
-        )
-        return set_pc(st, jnp.where(done, pc + 1, top_addr))
+        m.set("fs", ctr, m.t, jnp.maximum(fsp - 1, 0))
+        m.set("fsp", fsp + jnp.where(done, -2, 0), m.t)
+        set_pc(m, jnp.where(done, pc + 1, top_addr))
     B["doloop"] = op_doloop
 
-    B["i"] = lambda st: dpush(st, st.fs[st.cur, jnp.maximum(st.fsp[st.cur] - 1, 0)])
-    B["j"] = lambda st: dpush(st, st.fs[st.cur, jnp.maximum(st.fsp[st.cur] - 3, 0)])
+    def loop_index(back):
+        return lambda m: dpush(
+            m, m.get("fs", m.t, jnp.maximum(m.get("fsp", m.t) - back, 0))
+        )
+    B["i"] = loop_index(1)
+    B["j"] = loop_index(3)
 
-    B["unloop"] = lambda st: st._replace(fsp=st.fsp.at[st.cur].add(-2))
+    B["unloop"] = lambda m: m.set("fsp", m.get("fsp", m.t) - 2, m.t)
+    B["halt"] = lambda m: set_status(m, ST_HALT)
+    B["end"] = lambda m: set_status(m, jnp.where(m.t == 0, ST_DONE, ST_FREE))
 
-    B["halt"] = lambda st: set_status(st, ST_HALT)
-
-    def op_end(st):
-        s = jnp.where(st.cur == 0, ST_DONE, ST_FREE)
-        return set_status(st, s)
-    B["end"] = op_end
-
-    def op_dlit(st):
-        pc = cur_pc(st)
-        v = st.cs[jnp.clip(pc, 0, CS - 1)]
-        return set_pc(dpush(st, v), pc + 1)
+    def op_dlit(m):
+        pc = cur_pc(m)
+        dpush(m, m.get("cs", jnp.clip(pc, 0, CS - 1)))
+        set_pc(m, pc + 1)
     B["dlit"] = op_dlit
 
     # io / printing -----------------------------------------------------------
 
-    def op_print(st):
-        st, v = dpop1(st)
-        return out_write(st, OUT_NUM, v)
-    B["."] = op_print
-
-    def op_emit(st):
-        st, v = dpop1(st)
-        return out_write(st, OUT_CHR, v)
-    B["emit"] = op_emit
-
-    B["cr"] = lambda st: out_write(st, OUT_CHR, I32(10))
+    B["."] = lambda m: out_write(m, OUT_NUM, dpop1(m))
+    B["emit"] = lambda m: out_write(m, OUT_CHR, dpop1(m))
+    B["cr"] = lambda m: out_write(m, OUT_CHR, 10)
 
     MAXSTR = 64
 
-    def op_prstr(st):
-        pc = cur_pc(st)
-        ln = jnp.clip(st.cs[jnp.clip(pc, 0, CS - 1)], 0, MAXSTR)
-        idx = pc + 1 + jnp.arange(MAXSTR, dtype=I32)
-        chars = jnp.take(st.cs, jnp.clip(idx, 0, CS - 1))
-        p = st.outp
-        k = jnp.arange(MAXSTR, dtype=I32)
-        mask = (k < ln) & (p + k < OUTN)
-        base = 2 * (p + k)
-        out = st.out.at[jnp.where(mask, base, 2 * OUTN)].set(OUT_CHR, mode="drop")
-        out = out.at[jnp.where(mask, base + 1, 2 * OUTN)].set(chars, mode="drop")
-        st = st._replace(out=out, outp=jnp.minimum(p + ln, OUTN))
-        return set_pc(st, pc + 1 + ln)
+    def op_prstr(m):
+        pc = cur_pc(m)
+        ln = jnp.clip(m.get("cs", jnp.clip(pc, 0, CS - 1)), 0, MAXSTR)
+        p = m.get("outp")
+
+        def put(k):
+            ok = p + k < OUTN
+            m.set("out", OUT_CHR, 2 * (p + k), when=ok)
+            m.set("out", m.get("cs", jnp.clip(pc + 1 + k, 0, CS - 1)),
+                  2 * (p + k) + 1, when=ok)
+        _loop(ln, put)
+        m.set("outp", jnp.minimum(p + ln, OUTN))
+        set_pc(m, pc + 1 + ln)
     B["prstr"] = op_prstr
 
-    def op_vecprint(st):
-        st, arr = dpop1(st)
-        vals, ln, _ = vread(st, arr, MV)
-        return out_write_vec(st, vals, ln)
+    def op_vecprint(m):
+        arr = dpop1(m)
+        ln = vlen(m, arr)
+        p = m.get("outp")
+
+        def put(k):
+            ok = p + k < OUTN
+            m.set("out", OUT_NUM, 2 * (p + k), when=ok)
+            m.set("out", velem(m, arr, k), 2 * (p + k) + 1, when=ok)
+        _loop(ln, put)
+        m.set("outp", jnp.minimum(p + ln, OUTN))
     B["vecprint"] = op_vecprint
 
     def make_io_suspend(name):
         opc = isa.opcode[name]
 
-        def op(st):
+        def op(m):
             # Rewind pc so the host re-inspects the op; args stay on DS.
-            st = set_pc(st, cur_pc(st) - 1)
-            st = st._replace(io_op=st.io_op.at[st.cur].set(opc))
-            return set_status(st, ST_IOWAIT)
+            set_pc(m, cur_pc(m) - 1)
+            m.set("io_op", opc, m.t)
+            set_status(m, ST_IOWAIT)
         return op
 
     for _n in ("out", "in", "send", "receive"):
@@ -782,179 +873,157 @@ def make_core_step(
 
     # tasks (non-spawning) ----------------------------------------------------
 
-    B["yield"] = lambda st: set_status(st, ST_YIELD)
+    B["yield"] = lambda m: set_status(m, ST_YIELD)
 
-    def op_sleep(st):
-        st, ms_v = dpop1(st)
-        t = st.cur
-        st = st._replace(timeout=st.timeout.at[t].set(st.now + ms_v))
-        return set_status(st, ST_SLEEP)
+    def op_sleep(m):
+        ms_v = dpop1(m)
+        m.set("timeout", m.get("now") + ms_v, m.t)
+        set_status(m, ST_SLEEP)
     B["sleep"] = op_sleep
 
-    def op_await(st):
-        st, (ms_v, val, addr) = dpopn(st, 3)
-        t = st.cur
-        st = st._replace(
-            timeout=st.timeout.at[t].set(st.now + ms_v),
-            ev_addr=st.ev_addr.at[t].set(addr),
-            ev_val=st.ev_val.at[t].set(val),
-        )
-        return set_status(st, ST_EVENT)
+    def op_await(m):
+        ms_v, val, addr = dpopn(m, 3)
+        m.set("timeout", m.get("now") + ms_v, m.t)
+        m.set("ev_addr", addr, m.t)
+        m.set("ev_val", val, m.t)
+        set_status(m, ST_EVENT)
     B["await"] = op_await
 
-    B["taskid"] = lambda st: dpush(st, st.cur)
-    B["ms"] = lambda st: dpush(st, st.now)
-    B["steps"] = lambda st: dpush(st, st.steps)
+    B["taskid"] = lambda m: dpush(m, m.t)
+    B["ms"] = lambda m: dpush(m, m.get("now"))
+    B["steps"] = lambda m: dpush(m, m.get("steps"))
 
     # exceptions --------------------------------------------------------------
 
-    def op_exception(st):
-        st, (handler, exc) = dpopn(st, 2)
-        idx = jnp.clip(exc, 0, NUM_EXC - 1)
-        return st._replace(handlers=st.handlers.at[idx].set(handler))
+    def op_exception(m):
+        handler, exc = dpopn(m, 2)
+        m.set("handlers", handler, jnp.clip(exc, 0, NUM_EXC - 1))
     B["exception"] = op_exception
 
-    def op_catch(st):
-        t = st.cur
-        st = dpush(st, st.last_exc[t])
-        return st._replace(
-            last_exc=st.last_exc.at[t].set(0),
-            catch_pc=st.catch_pc.at[t].set(cur_pc(st) - 1),
-            catch_rsp=st.catch_rsp.at[t].set(st.rsp[t]),
-        )
+    def op_catch(m):
+        dpush(m, m.get("last_exc", m.t))
+        m.set("last_exc", 0, m.t)
+        m.set("catch_pc", cur_pc(m) - 1, m.t)
+        m.set("catch_rsp", m.get("rsp", m.t), m.t)
     B["catch"] = op_catch
 
-    def op_throw(st):
-        st, exc = dpop1(st)
-        return raise_exc(st, jnp.clip(exc, 1, NUM_EXC - 1))
-    B["throw"] = op_throw
+    B["throw"] = lambda m: raise_exc(m, jnp.clip(dpop1(m), 1, NUM_EXC - 1))
 
-    # fixed-point DSP scalars (LUT gathers from the kernel operand) -----------
+    # fixed-point DSP scalars (LUT loads from the table operand) --------------
 
-    TB["sin"] = un_op_t(lambda v, tb: _fpsin_t(v, tb).astype(I32))
-    TB["log"] = un_op_t(lambda v, tb: (_fplog10_t(v, tb) * 10).astype(I32))
-    TB["sigmoid"] = un_op_t(lambda v, tb: _fpsigmoid_t(v, tb).astype(I32))
+    B["sin"] = un_op_t(lambda v, m: _fpsin_t(v, m).astype(I32))
+    B["log"] = un_op_t(lambda v, m: (_fplog10_t(v, m) * 10).astype(I32))
+    B["sigmoid"] = un_op_t(lambda v, m: _fpsigmoid_t(v, m).astype(I32))
     B["relu"] = un_op(lambda v: jnp.maximum(v, 0))
     B["sqrt"] = un_op(lambda v: fpsqrt_jnp(v).astype(I32))
 
     # vector / ANN ops --------------------------------------------------------
 
-    def op_vecload(st):
-        st, (src, srcoff, dst) = dpopn(st, 3)
-        _, ln, _ = vread(st, dst, MV)
-        vals, _, _ = vread(st, src + srcoff, MV, length=ln)
-        return vwrite(st, dst, vals, ln)
+    def op_vecload(m):
+        src, srcoff, dst = dpopn(m, 3)
+        vcompute(m, dst, vlen(m, dst), lambda k: velem(m, src + srcoff, k))
     B["vecload"] = op_vecload
 
-    def op_vecscale(st):
-        st, (src, dst, saddr) = dpopn(st, 3)
-        _, ln, _ = vread(st, dst, MV)
-        vals, _, _ = vread(st, src, MV, length=ln)
-        svals, _, _ = vread(st, saddr, MV, length=ln)
-        return vwrite(st, dst, vscale(vals, svals, jnp.bool_(True)), ln)
+    def op_vecscale(m):
+        src, dst, saddr = dpopn(m, 3)
+        vcompute(
+            m, dst, vlen(m, dst),
+            lambda k: vscale(velem(m, src, k), velem(m, saddr, k), True),
+        )
     B["vecscale"] = op_vecscale
 
     def make_eltwise(f):
-        def op(st):
-            st, (a, b, dst, saddr) = dpopn(st, 4)
-            _, ln, _ = vread(st, dst, MV)
-            av, _, _ = vread(st, a, MV, length=ln)
-            bv, _, _ = vread(st, b, MV, length=ln)
-            r = f(av, bv)
-            r = apply_scalevec(st, r, ln, saddr)
-            return vwrite(st, dst, r, ln)
+        def op(m):
+            a, b, dst, saddr = dpopn(m, 4)
+            vcompute(m, dst, vlen(m, dst), scaled_elem(
+                m, saddr, lambda k: f(velem(m, a, k), velem(m, b, k))
+            ))
         return op
 
     B["vecadd"] = make_eltwise(lambda a, b: a + b)
     B["vecmul"] = make_eltwise(lambda a, b: a * b)
 
-    def op_vecfold(st):
-        # MXU lowering (the fixmatmul idiom): gather the (n x m) weight
-        # matrix and contract with dot_general at int32 — byte-exact with
-        # interp's sum-of-products because int32 wraparound addition is
-        # order-independent.
-        st, (inv, wgt, outv, saddr) = dpopn(st, 4)
-        iv, n, imask = vread(st, inv, MV)
-        _, m, _ = vread(st, outv, MV)
-        ii = jnp.arange(MV, dtype=I32)[:, None]
-        jj = jnp.arange(MV, dtype=I32)[None, :]
-        flat_idx = wgt + ii * m + jj
-        in_mem = wgt >= MEM_BASE
-        cs_w = jnp.take(st.cs, jnp.clip(flat_idx, 0, CS - 1))
-        mem_w = jnp.take(st.mem, jnp.clip(flat_idx - MEM_BASE, 0, MEM - 1))
-        w = jnp.where(in_mem, mem_w, cs_w)
-        wmask = (ii < n) & (jj < m)
-        w = jnp.where(wmask, w, 0)
-        acc = lax.dot_general(
-            iv, w, (((0,), (0,)), ((), ())), preferred_element_type=I32
-        ).astype(I32)
-        acc = apply_scalevec(st, acc, m, saddr)
-        return vwrite(st, outv, acc, m)
+    def dot(n, x_of, y_of):
+        """sum_{i<n} x_of(i) * y_of(i), int32 wraparound."""
+        return lax.fori_loop(
+            0, n, lambda i, acc: acc + x_of(i) * y_of(i), I32(0)
+        )
+
+    def op_vecfold(m):
+        # out[j] = sum_{i<n} in[i] * w[i*m + j] for j < m: the (n x m)
+        # weight matrix is read in place, row-major from wgt.
+        inv, wgt, outv, saddr = dpopn(m, 4)
+        n, mm = vlen(m, inv), vlen(m, outv)
+        vcompute(m, outv, mm, scaled_elem(m, saddr, lambda j: dot(
+            n, lambda i: velem(m, inv, i), lambda i: velem(m, wgt, i * mm + j)
+        )))
     B["vecfold"] = op_vecfold
 
-    def op_vecmap(st, tb):
-        st, (src, dst, fn, saddr) = dpopn(st, 4)
-        _, ln, _ = vread(st, dst, MV)
-        vals, _, _ = vread(st, src, MV, length=ln)
-        mapped = lax.switch(
-            jnp.clip(fn, 0, 4),
-            [
-                lambda v: _fpsigmoid_t(v, tb).astype(I32),
-                lambda v: jnp.maximum(v, 0),
-                lambda v: _fpsin_t(v, tb).astype(I32),
-                lambda v: (_fplog10_t(v, tb) * 10).astype(I32),
-                lambda v: fpsqrt_jnp(v).astype(I32),
-            ],
-            vals,
-        )
-        mapped = apply_scalevec(st, mapped, ln, saddr)
-        return vwrite(st, dst, mapped, ln)
-    TB["vecmap"] = op_vecmap
+    def vec_fn(m, fn, v):
+        # All five maps, then a select: no branch in the element loop.
+        fn = jnp.clip(fn, 0, 4)
+        mapped = [
+            _fpsigmoid_t(v, m).astype(I32),
+            jnp.maximum(v, 0),
+            _fpsin_t(v, m).astype(I32),
+            (_fplog10_t(v, m) * 10).astype(I32),
+            fpsqrt_jnp(v).astype(I32),
+        ]
+        out = mapped[0]
+        for i, r in enumerate(mapped[1:], 1):
+            out = jnp.where(fn == i, r, out)
+        return out
 
-    def op_dotprod(st):
-        st, (a, b) = dpopn(st, 2)
-        av, n, _ = vread(st, a, MV)
-        bv, _, _ = vread(st, b, MV, length=n)
-        r = lax.dot_general(
-            av, bv, (((0,), (0,)), ((), ())), preferred_element_type=I32
-        )
-        return dpush(st, r.astype(I32))
+    def op_vecmap(m):
+        src, dst, fn, saddr = dpopn(m, 4)
+        vcompute(m, dst, vlen(m, dst), scaled_elem(
+            m, saddr, lambda k: vec_fn(m, fn, velem(m, src, k))
+        ))
+    B["vecmap"] = op_vecmap
+
+    def op_dotprod(m):
+        a, b = dpopn(m, 2)
+        dpush(m, dot(vlen(m, a), lambda i: velem(m, a, i),
+                     lambda i: velem(m, b, i)))
     B["dotprod"] = op_dotprod
 
-    def op_vecmax(st):
-        st, arr = dpop1(st)
-        vals, ln, mask = vread(st, arr, MV)
-        vals = jnp.where(mask, vals, jnp.iinfo(jnp.int32).min)
-        return dpush(st, jnp.argmax(vals).astype(I32))
+    def op_vecmax(m):
+        # First index of the maximum over the header length (index 0 for
+        # an empty vector), as argmax over the INT_MIN-masked window.
+        arr = dpop1(m)
+        ln = vlen(m, arr)
+        lo = I32(jnp.iinfo(jnp.int32).min)
+        first = jnp.where(ln > 0, velem(m, arr, 0), lo)
+
+        def step(k, carry):
+            best, idx = carry
+            v = velem(m, arr, k)
+            return jnp.where(v > best, v, best), jnp.where(v > best, k, idx)
+        _, idx = lax.fori_loop(1, ln, step, (first, I32(0)))
+        dpush(m, idx)
     B["vecmax"] = op_vecmax
 
-    def iir_lowpass(vals, ln, k):
-        """y_i = y_{i-1} + k*(x_i - y_{i-1})/1000, y_{-1} = x_0."""
-        def step(y, xm):
-            x, m = xm
-            y2 = y + _truncdiv(k * (x - y), I32(1000))
-            y2 = jnp.where(m, y2, y)
-            return y2, y2
-        mask = jnp.arange(MV) < ln
-        y0 = vals[0]
-        _, ys = lax.scan(step, y0, (vals, mask))
-        return ys
-
     def make_filter(kind):
-        def op(st):
-            st, (arr, off, ln_req, k) = dpopn(st, 4)
+        # y_k = y_{k-1} + c*(x_k - y_{k-1})/1000 with y_{-1} = x_0; hull
+        # filters |x|, highp writes x - y.
+        def op(m):
+            arr, off, ln_req, c = dpopn(m, 4)
             base = arr + off
-            hdr_ln = mread(st, arr - 1)
+            hdr_ln = mread(m, arr - 1)
             ln = jnp.clip(jnp.minimum(ln_req, hdr_ln - off), 0, MV)
-            vals, _, _ = vread(st, base, MV, length=ln)
-            if kind == "hull":
-                x = jnp.abs(vals)
-                y = iir_lowpass(x, ln, k)
-            elif kind == "lowp":
-                y = iir_lowpass(vals, ln, k)
-            else:  # highp
-                y = vals - iir_lowpass(vals, ln, k)
-            return vwrite(st, base, y, ln)
+
+            def x_of(k):
+                v = jnp.where(k < ln, velem(m, base, k), 0)
+                return jnp.abs(v) if kind == "hull" else v
+
+            def step(k, y):
+                y = y + _truncdiv(c * (x_of(k) - y), I32(1000))
+                m.set_cell(
+                    m.lay.tmp + k, x_of(k) - y if kind == "highp" else y
+                )
+                return y
+            lax.fori_loop(0, ln, step, x_of(0))
+            _loop(ln, lambda k: vset(m, base, k, m.cell(m.lay.tmp + k)))
         return op
 
     B["hull"] = make_filter("hull")
@@ -965,131 +1034,103 @@ def make_core_step(
 
     num_ops = isa.num_ops
     sup = supported_mask(isa)
-    branches: list[Callable] = []
-    identity = lambda st, tb: st    # declined opcodes bail before dispatch
+    bodies: list[Callable] = []
     for code in range(num_ops):
         nm = isa.name[code]
-        if sup[code]:
-            if nm in TB:
-                fn = TB[nm]
-            elif nm in B:
-                fn = (lambda f: lambda st, tb: f(st))(B[nm])
-            else:
-                raise RuntimeError(
-                    f"opcode {nm!r} claimed by SUPPORTED_WORDS but missing "
-                    f"from the vmloop branch table"
-                )
+        if not sup[code]:
+            bodies.append(B["nop"])     # declined opcodes bail before dispatch
+        elif nm in B:
+            bodies.append(B[nm])
         else:
-            fn = identity
-        branches.append(fn)
-    branches.append(identity)   # >= num_ops (FIOS/trap): always bails first
-
-    def exec_op(st, opcode, tb: Tables):
-        code = jnp.clip(opcode, 0, num_ops).astype(I32)
-        if elide_checks:
-            # Verified program: the stack pre-check is statically dead.
-            return lax.switch(code, branches, st, tb)
-        t = st.cur
-        din = tb.din[code]
-        dout = tb.dout[code]
-        fin = tb.fin[code]
-        fout = tb.fout[code]
-        under = (st.dsp[t] < din) | (st.fsp[t] < fin)
-        over = (st.dsp[t] - din + dout > DS) | (st.fsp[t] - fin + fout > FS)
-        bad = under | over
-
-        def good(s):
-            return lax.switch(code, branches, s, tb)
-        return lax.cond(bad, lambda s: raise_exc(s, EXC_STACK), good, st)
-
-    def step_instr(st: CoreState, tb: Tables) -> CoreState:
-        t = st.cur
-        pc = st.pc[t]
-        pc_ok = (pc >= 0) & (pc < CS)
-        instr = st.cs[jnp.clip(pc, 0, CS - 1)]
-        tag = instr & 3
-        payload = (instr >> 2).astype(I32)
-
-        def case_op(s):
-            s = set_pc(s, pc + 1)
-            return exec_op(s, payload, tb)
-
-        def case_lit(s):
-            s = set_pc(s, pc + 1)
-            if elide_checks:
-                return dpush(s, payload)
-            over = s.dsp[t] >= DS
-            return lax.cond(
-                over, lambda x: raise_exc(x, EXC_STACK), lambda x: dpush(x, payload), s
+            raise RuntimeError(
+                f"opcode {nm!r} claimed by SUPPORTED_WORDS but missing "
+                f"from the vmloop branch table"
             )
+    bodies.append(B["nop"])   # >= num_ops (FIOS/trap): always bails first
 
-        def case_call(s):
-            over = s.rsp[t] >= RS
+    # One more branch past the opcode space: the stack pre-check's fault.
+    bodies.append(lambda m: raise_exc(m, EXC_STACK))
+    stack_fault = len(bodies) - 1
 
-            def do(x):
-                x = x._replace(
-                    rs=x.rs.at[t, jnp.clip(x.rsp[t], 0, RS - 1)].set(pc + 1),
-                    rsp=x.rsp.at[t].add(1),
-                )
-                return set_pc(x, payload)
-            return lax.cond(over, lambda x: raise_exc(x, EXC_STACK), do, s)
+    def exec_op(m, opcode):
+        code = jnp.clip(opcode, 0, num_ops).astype(I32)
+        if not elide_checks:
+            # (A verified program skips this: the pre-check is dead.)
+            dsp, fsp = m.get("dsp", m.t), m.get("fsp", m.t)
+            din, dout = m.tab("din", code), m.tab("dout", code)
+            fin, fout = m.tab("fin", code), m.tab("fout", code)
+            under = (dsp < din) | (fsp < fin)
+            over = (dsp - din + dout > DS) | (fsp - fin + fout > FS)
+            code = jnp.where(under | over, stack_fault, code)
 
-        def case_bad(s):
-            return raise_exc(set_pc(s, pc + 1), EXC_TRAP)
+        # Bodies run for their effect; some also return a popped value.
+        def run(f):
+            f(m)
+        _tree_switch(code, [lambda f=f: run(f) for f in bodies])
 
-        st = lax.cond(
-            pc_ok,
-            lambda s: lax.switch(tag, [case_op, case_lit, case_call, case_bad], s),
-            lambda s: set_status(raise_exc(s, EXC_TRAP), ST_ERR),
-            st,
-        )
-        st = st._replace(steps=st.steps + 1)
+    def fetch(m):
+        pc = cur_pc(m)
+        pc_ok = (pc >= 0) & (pc < CS)
+        instr = m.get("cs", jnp.clip(pc, 0, CS - 1))
+        return pc, pc_ok, instr & 3, (instr >> 2).astype(I32)
 
-        # Exception dispatch (identical to interp.step_instr).
-        exc = st.pending_exc[st.cur]
+    def step_instr(m: _Node) -> None:
+        # Only the opcode tag needs a branch; the literal, call, bad-tag and
+        # bad-pc cases are a few writes each, predicated on their case so
+        # the kernel's control flow stays shallow.
+        pc, pc_ok, tag, payload = fetch(m)
+        is_op = pc_ok & (tag == 0)
+        lax.cond(is_op, lambda: (set_pc(m, pc + 1), exec_op(m, payload))[1],
+                 lambda: None)
 
-        def dispatch(s):
-            t2 = s.cur
-            code = jnp.clip(s.pending_exc[t2], 0, NUM_EXC - 1)
-            handler = s.handlers[code]
-            has = handler > 0
+        dsp, rsp = m.get("dsp", m.t), m.get("rsp", m.t)
+        is_lit = pc_ok & (tag == 1)
+        is_call = pc_ok & (tag == 2)
+        lit_ok = is_lit if elide_checks else is_lit & (dsp < DS)
+        call_ok = is_call & (rsp < RS)
+        new_pc = jnp.where(call_ok, payload, pc + 1)
+        set_pc(m, new_pc, when=~is_op & pc_ok & ~(is_call & ~call_ok))
+        m.set("ds", payload, m.t, jnp.clip(dsp, 0, DS - 1), when=lit_ok)
+        m.set("dsp", dsp + 1, m.t, when=lit_ok)
+        m.set("rs", pc + 1, m.t, jnp.clip(rsp, 0, RS - 1), when=call_ok)
+        m.set("rsp", rsp + 1, m.t, when=call_ok)
+        raise_exc(m, EXC_STACK, when=(is_lit & ~lit_ok) | (is_call & ~call_ok))
+        raise_exc(m, EXC_TRAP, when=(pc_ok & (tag == 3)) | ~pc_ok)
+        set_status(m, ST_ERR, when=~pc_ok)
+        m.set("steps", m.get("steps") + 1)
 
-            def with_handler(x):
-                crsp = jnp.clip(x.catch_rsp[t2], 0, RS - 1)
-                x = x._replace(
-                    rs=x.rs.at[t2, crsp].set(x.catch_pc[t2]),
-                    rsp=x.rsp.at[t2].set(crsp + 1),
-                    last_exc=x.last_exc.at[t2].set(code),
-                    pending_exc=x.pending_exc.at[t2].set(0),
-                )
-                return set_pc(x, handler)
+        # Exception dispatch (identical to interp.step_instr), predicated.
+        exc = m.get("pending_exc", m.t) > 0
+        code = jnp.clip(m.get("pending_exc", m.t), 0, NUM_EXC - 1)
+        handler = m.get("handlers", code)
+        has = exc & (handler > 0)
+        crsp = jnp.clip(m.get("catch_rsp", m.t), 0, RS - 1)
+        m.set("rs", m.get("catch_pc", m.t), m.t, crsp, when=has)
+        m.set("rsp", crsp + 1, m.t, when=has)
+        m.set("last_exc", code, m.t, when=exc)
+        m.set("pending_exc", 0, m.t, when=exc)
+        set_pc(m, handler, when=has)
+        set_status(m, ST_ERR, when=exc & ~has)
 
-            def no_handler(x):
-                x = x._replace(
-                    last_exc=x.last_exc.at[t2].set(code),
-                    pending_exc=x.pending_exc.at[t2].set(0),
-                )
-                return set_status(x, ST_ERR)
-            return lax.cond(has, with_handler, no_handler, s)
-        st = lax.cond(exc > 0, dispatch, lambda s: s, st)
-        return st
-
-    def instr_supported(st: CoreState, tb: Tables):
+    def instr_supported(m: _Node):
         """True iff the *next* instruction may execute in-kernel.  Non-OP
         tags and invalid pcs are always supported (they are the exact trap/
         literal/call semantics of the lax interpreter); OP tags consult the
         claim mask — index ``num_ops`` (FIOS and out-of-table traps) is
         False, so those bail to the host path."""
-        t = st.cur
-        pc = st.pc[t]
-        pc_ok = (pc >= 0) & (pc < CS)
-        instr = st.cs[jnp.clip(pc, 0, CS - 1)]
-        tag = instr & 3
-        payload = (instr >> 2).astype(I32)
-        op_ok = tb.sup[jnp.clip(payload, 0, num_ops)] != 0
+        _, pc_ok, tag, payload = fetch(m)
+        op_ok = m.tab("sup", jnp.clip(payload, 0, num_ops)) != 0
         return jnp.where(pc_ok & (tag == 0), op_ok, True)
 
-    return step_instr, instr_supported
+    def retire_bin(m: _Node):
+        """The obs histogram bin of the next instruction (the
+        ``repro.obs.metrics`` layout: opcodes, fios/trap, lit, call,
+        invalid)."""
+        _, pc_ok, tag, payload = fetch(m)
+        b = jnp.where(tag == 0, jnp.clip(payload, 0, num_ops), num_ops + tag)
+        return jnp.where(pc_ok, b, num_ops + 3).astype(I32)
+
+    return CoreStep(step_instr, instr_supported, retire_bin, fetch)
 
 
 def make_run_core(
@@ -1098,90 +1139,65 @@ def make_run_core(
     obs: bool = False,
     elide_checks: bool = False,
 ):
-    """Returns ``run_core(core, tables, steps) -> (core, n_exec, bailed,
-    bail_op)``: the fetch/dispatch/execute loop of Alg. 1, restricted to the
-    claimed opcode set.  Stops on slice exhaustion, a status change
-    (suspend/halt/error), or the first unclaimed opcode — in the last case
-    *before* executing it, so the host-side lax interpreter resumes from
-    identical state.  ``bail_op`` is the opcode that caused the bail
-    (clipped to ``num_ops`` for FIOS/trap), or -1 when the loop did not
-    bail — the raw feed for the per-opcode bail histogram.
+    """Returns ``run_core(row, tab, steps)``: the fetch/dispatch/execute
+    loop of Alg. 1 over one node's row ref ``(1, width)`` (see
+    :class:`Layout`) and the flat table ref, restricted to the claimed
+    opcode set.  Stops on slice exhaustion, a status change (suspend/halt/
+    error), or the first unclaimed opcode — in the last case *before*
+    executing it, so the host-side lax interpreter resumes from identical
+    state.  It writes its results into the row: ``n_exec``, ``bailed``
+    (0/1) and ``bail_op``, the opcode that caused the bail (clipped to
+    ``num_ops`` for FIOS/trap) or -1 when the loop did not bail — the raw
+    feed for the per-opcode bail histogram.
 
-    With ``obs=True`` the loop also carries a ``(num_ops + 4,)`` retirement
-    histogram (the ``repro.obs.metrics`` bin layout: ISA opcodes, then
-    fios/trap, lit, call, invalid) and returns it as a fifth output.  Only
-    *retired* steps are binned — the bailing instruction is not (the lax
-    tail retires and counts it), so kernel + tail histograms always sum to
-    exactly what a pure-lax slice would count."""
+    With ``obs=True`` the loop also counts retirements into the row's
+    ``(num_ops + 4,)`` histogram (the ``repro.obs.metrics`` bin layout:
+    ISA opcodes, then fios/trap, lit, call, invalid).  Only *retired* steps
+    are binned — the bailing instruction is not (the lax tail retires and
+    counts it), so kernel + tail histograms always sum to exactly what a
+    pure-lax slice would count."""
     isa = isa or get_isa()
-    CS = cfg.cs_size
     num_ops = isa.num_ops
-    step_instr, instr_supported = make_core_step(cfg, isa, elide_checks)
+    lay = Layout(cfg, isa)
+    _, spans = flat_tables(isa)
+    core_step = make_core_step(cfg, isa, elide_checks)
 
-    def bin_of(s: CoreState):
-        t = s.cur
-        pc = s.pc[t]
-        pc_ok = (pc >= 0) & (pc < CS)
-        instr = s.cs[jnp.clip(pc, 0, CS - 1)]
-        tag = instr & 3
-        payload = (instr >> 2).astype(I32)
-        b = jnp.where(tag == 0, jnp.clip(payload, 0, num_ops), num_ops + tag)
-        return jnp.where(pc_ok, b, num_ops + 3).astype(I32)
+    def run_core(row, tab, steps):
+        m = _Node(lay, row, tab, spans)
 
-    def run_core_obs(core: CoreState, tb: Tables, steps):
+        # The carry holds the task's status and whether the next
+        # instruction is claimed: a while_loop's cond may not read refs
+        # (interpret mode would see stale values).
+        def status():
+            return m.get("tstatus", m.t)
+
+        def supported():
+            return core_step.supported(m).astype(I32)
+
         def cond(carry):
-            s, n, bailed, h = carry
-            return (n < steps) & (s.tstatus[s.cur] == ST_RUN) & (~bailed)
+            n, ok, st = carry
+            return (n < steps) & (st == ST_RUN) & (ok != 0)
 
         def body(carry):
-            s, n, bailed, h = carry
-            ok = instr_supported(s, tb)
-            h = h.at[bin_of(s)].add(jnp.where(ok, 1, 0).astype(I32))
-            s = lax.cond(ok, lambda x: step_instr(x, tb), lambda x: x, s)
-            return s, n + jnp.where(ok, 1, 0).astype(I32), ~ok, h
+            n, _, _ = carry
+            if obs:
+                b = lay.hist + core_step.retire_bin(m)
+                m.set_cell(b, m.cell(b) + 1)
+            core_step.step(m)
+            return n + 1, supported(), status()
 
-        core, n, bailed, hist = lax.while_loop(
-            cond, body,
-            (core, jnp.int32(0), jnp.bool_(False),
-             jnp.zeros(num_ops + 4, I32)),
-        )
-        pc = core.pc[core.cur]
-        instr = core.cs[jnp.clip(pc, 0, CS - 1)]
-        payload = (instr >> 2).astype(I32)
-        bail_op = jnp.where(bailed, jnp.clip(payload, 0, num_ops), I32(-1))
-        return core, n, bailed, bail_op, hist
-
-    def run_core(core: CoreState, tb: Tables, steps):
-        def cond(carry):
-            s, n, bailed = carry
-            return (n < steps) & (s.tstatus[s.cur] == ST_RUN) & (~bailed)
-
-        def body(carry):
-            s, n, bailed = carry
-            ok = instr_supported(s, tb)
-            s = lax.cond(ok, lambda x: step_instr(x, tb), lambda x: x, s)
-            return s, n + jnp.where(ok, 1, 0).astype(I32), ~ok
-
-        core, n, bailed = lax.while_loop(
-            cond, body, (core, jnp.int32(0), jnp.bool_(False))
-        )
+        n, ok, st = lax.while_loop(cond, body, (I32(0), supported(), status()))
+        # The loop bails when it stopped only because the next instruction
+        # is declined.
+        bailed = ((ok == 0) & (n < steps) & (st == ST_RUN)).astype(I32)
         # bailed implies pc_ok & tag == 0 (instr_supported is True for
         # every other shape), so the payload at pc is the declined opcode.
-        pc = core.pc[core.cur]
-        instr = core.cs[jnp.clip(pc, 0, CS - 1)]
-        payload = (instr >> 2).astype(I32)
-        bail_op = jnp.where(bailed, jnp.clip(payload, 0, num_ops), I32(-1))
-        return core, n, bailed, bail_op
+        _, _, _, payload = core_step.fetch(m)
+        m.set_cell(lay.n_exec, n)
+        m.set_cell(lay.bailed, bailed)
+        m.set_cell(
+            lay.bail_op, jnp.where(bailed != 0, jnp.clip(payload, 0, num_ops), -1)
+        )
 
-    return run_core_obs if obs else run_core
+    return run_core
 
-
-def vmloop_ref(S: VMState, steps: int, cfg: VMConfig, isa: ISA | None = None):
-    """Pure-jnp oracle for the kernel: the same ``run_core`` loop vmapped
-    over the node axis of a stacked fleet state.  Returns
-    ``(S', n_exec (N,), bailed (N,) bool, bail_op (N,))``."""
-    run_core = make_run_core(cfg, isa)
-    tb = Tables(*[jnp.asarray(x) for x in make_tables(isa)])
-    core = core_of(S)
-    core, n_exec, bailed, bail_op = jax.vmap(lambda c: run_core(c, tb, steps))(core)
-    return merge_core(S, core), n_exec, bailed, bail_op

@@ -2,14 +2,16 @@
 
 One grid program per fleet node.  The node's entire kernel-visible machine
 state — code segment, DIOS memory, the per-task data/return/loop stacks,
-pointers, and exception table — is block-mapped into VMEM; the program then
-runs up to ``steps`` fetch/decode/execute iterations of
-:func:`repro.kernels.vmloop.ref.make_run_core` *entirely on chip*: a
-``lax.while_loop`` around a flat ``lax.switch`` branch table (the paper's
-branch look-up table decoder, §3.10), with zero HBM traffic between
-instructions.  This is the repo's analogue of the paper's FPGA
-implementation of the very same VM: one bytecode semantics, one software
-(lax/oracle) engine and one "hardware" (Pallas) engine, byte-exact.
+pointers, and exception table — travels as one flat int32 row
+(:class:`repro.kernels.vmloop.ref.Layout`).  The program DMAs its row from
+HBM into scalar memory (SMEM), runs up to ``steps`` fetch/decode/execute
+iterations of :func:`repro.kernels.vmloop.ref.make_run_core` *entirely on
+chip* — a ``lax.while_loop`` around a branch table over the opcode space
+(the paper's branch look-up table decoder, §3.10) with zero HBM traffic
+between instructions — and DMAs the row back in place.  This is the repo's
+analogue of the paper's FPGA implementation of the very same VM: one
+bytecode semantics, one software (lax/oracle) engine and one "hardware"
+(Pallas) engine, byte-exact.
 
 Bail-out protocol: the loop stops *before* the first instruction outside
 the claimed opcode set (now only ``task`` spawn, ``rnd``, and FIOS calls —
@@ -24,41 +26,23 @@ ST_IOWAIT) executes in-kernel and the loop exits on the status change with
 ``bailed`` false; delivery belongs to the host service loop and the
 collective router between kernel invocations.
 
-Grid/BlockSpec layout: grid ``(nodes_per_shard,)``; every input/output
-block is one node's row (``(1, ...)`` blocks, index map ``i -> (i, 0...)``),
-so node ``i``'s state is the only VMEM-resident data of program ``i`` and
-the grid is embarrassingly parallel (``dimension_semantics=("parallel",)``).
-Scalars ride as ``(1, 1)`` blocks (TPU scalars must be 2-D).
+Layout: the rows are one ``(N, 1, width)`` array left in HBM
+(``memory_space=ANY``) and aliased from input to output; grid ``(N,)``,
+program ``i`` copies row ``i`` to a ``(1, width)`` SMEM scratch and back.
+The unit axis keeps each node's row whole under the TPU's tiling, and the
+tables ride as one small whole-array SMEM operand.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.config import VMConfig
 from repro.core.vm.spec import ISA, get_isa
-from repro.kernels import tpu_compiler_params
-from repro.kernels.vmloop.ref import (
-    CORE_FIELDS,
-    MUTATED_FIELDS,
-    SCALAR_FIELDS,
-    CoreState,
-    Tables,
-    make_run_core,
-    make_tables,
-)
-
-
-def _spec(per_node_shape: tuple[int, ...]) -> pl.BlockSpec:
-    """One node's row of a stacked field: block (1, ...), block index i."""
-    nrest = len(per_node_shape)
-    return pl.BlockSpec(
-        (1,) + per_node_shape,
-        lambda i, nrest=nrest: (i,) + (0,) * nrest,
-    )
+from repro.kernels.vmloop.ref import CoreState, Layout, flat_tables, make_run_core
 
 
 def vmloop_call(
@@ -81,10 +65,9 @@ def vmloop_call(
 
     ``obs=True`` compiles the *counting* run_core variant: the kernel
     additionally accumulates a per-node ``(num_ops + 4,)`` retirement
-    histogram in VMEM and emits it as a fifth result ``op_hist
-    (N, num_ops + 4) int32``.  This is a distinct kernel (extra output
-    block, extra carry in the while loop) — the default path is unchanged
-    and pays zero extra device outputs.
+    histogram in its row and it is returned as a fifth result ``op_hist
+    (N, num_ops + 4) int32``.  This is a distinct kernel — the default
+    path is unchanged.
 
     ``elide_checks=True`` compiles the verified-program fast path: the
     per-step stack pre-check disappears from the kernel body at build time
@@ -93,85 +76,35 @@ def vmloop_call(
     """
     isa = isa or get_isa()
     N = core.pc.shape[0]
+    lay = Layout(cfg, isa)
     run_core = make_run_core(cfg, isa, obs=obs, elide_checks=elide_checks)
-    nbins = isa.num_ops + 4
-    # Constant dispatch + LUT tables ride along as (1, L_t) operands
-    # replicated to every grid program (a kernel cannot capture array
-    # constants); each table keeps its own length.
-    tables = make_tables(isa)
-    tab_lens = [int(np.asarray(t).shape[0]) for t in tables]
+    tab = jnp.asarray(flat_tables(isa)[0])
 
-    # TPU scalars must be 2-D: stacked () fields travel as (N, 1) blocks.
-    core2 = core._replace(
-        **{f: getattr(core, f).reshape(N, 1) for f in SCALAR_FIELDS}
-    )
-    ins = [getattr(core2, f) for f in CORE_FIELDS]
-    ins += [jnp.asarray(t).reshape(1, L) for t, L in zip(tables, tab_lens)]
-    per_shape = {f: tuple(getattr(core2, f).shape[1:]) for f in CORE_FIELDS}
-    out_fields = list(MUTATED_FIELDS) + ["n_exec", "bailed", "bail_op"]
-    out_shape = {**per_shape, "n_exec": (1,), "bailed": (1,), "bail_op": (1,)}
-    if obs:
-        out_fields.append("op_hist")
-        out_shape["op_hist"] = (nbins,)
-    n_core = len(CORE_FIELDS)
-    n_tab = len(Tables._fields)
+    def kernel(tab_ref, rows_in, rows_out, row):
+        i = pl.program_id(0)
+        pltpu.sync_copy(rows_in.at[i], row)
+        run_core(row, tab_ref, steps)
+        pltpu.sync_copy(row, rows_out.at[i])
 
-    def kernel(*refs):
-        in_refs = refs[:n_core]
-        tab_refs = refs[n_core:n_core + n_tab]
-        out_refs = refs[n_core + n_tab:]
-        vals = {}
-        for f, r in zip(CORE_FIELDS, in_refs):
-            v = r[...][0]                       # (1, ...) block -> node row
-            if f in SCALAR_FIELDS:
-                v = v[0]                        # (1,) -> ()
-            vals[f] = v
-        st = CoreState(**vals)
-        tb = Tables(*[r[...][0] for r in tab_refs])
-        if obs:
-            st, n, bailed, bail_op, hist = run_core(st, tb, steps)
-            out_refs[-1][0] = hist
-            scalar_refs = out_refs[-4:-1]
-        else:
-            st, n, bailed, bail_op = run_core(st, tb, steps)
-            scalar_refs = out_refs[-3:]
-        for f, r in zip(MUTATED_FIELDS, out_refs):
-            if f in SCALAR_FIELDS:
-                r[0, 0] = getattr(st, f)
-            else:
-                r[0] = getattr(st, f)
-        scalar_refs[0][0, 0] = n
-        scalar_refs[1][0, 0] = jnp.where(bailed, 1, 0).astype(jnp.int32)
-        scalar_refs[2][0, 0] = bail_op
-
-    tab_specs = [
-        pl.BlockSpec((1, L), lambda i: (0, 0)) for L in tab_lens
-    ]
-    outs = pl.pallas_call(
+    rows = pl.pallas_call(
         kernel,
         grid=(N,),
-        in_specs=[_spec(per_shape[f]) for f in CORE_FIELDS]
-        + tab_specs,
-        out_specs=[_spec(out_shape[f]) for f in out_fields],
-        out_shape=[
-            jax.ShapeDtypeStruct((N,) + out_shape[f], jnp.int32)
-            for f in out_fields
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        compiler_params=tpu_compiler_params(
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((N, 1, lay.width), jnp.int32),
+        scratch_shapes=[pltpu.SMEM((1, lay.width), jnp.int32)],
+        input_output_aliases={1: 0},
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
-    )(*ins)
+        name="vmloop",
+    )(tab, lay.pack(core))
 
-    named = dict(zip(out_fields, outs))
-    n_exec = named.pop("n_exec")[:, 0]
-    bailed = named.pop("bailed")[:, 0].astype(bool)
-    bail_op = named.pop("bail_op")[:, 0]
-    op_hist = named.pop("op_hist") if obs else None
-    for f in SCALAR_FIELDS:
-        if f in named:
-            named[f] = named[f][:, 0]
-    core = core._replace(**named)
+    core, n_exec, bailed, bail_op, op_hist = lay.unpack(rows)
     if obs:
         return core, n_exec, bailed, bail_op, op_hist
     return core, n_exec, bailed, bail_op

@@ -1,0 +1,28 @@
+"""JAX's persistent compilation cache, in one fixed place.
+
+The fleet's interpreters and the vmloop kernel take tens of seconds to
+compile per ``(VMConfig, n)``.  Entry points (``chip_smoke.py``,
+``benchmarks/run.py``, the examples) call :func:`enable_compile_cache`
+first, so a second process with the same shapes reuses the compiled
+programs.  The cache directory is part of each entry's key, so it never
+moves: ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it (JAX
+reads that variable itself), else ``.jax_cache`` at the checkout's root.
+"""
+
+from __future__ import annotations
+
+import os
+from pathlib import Path
+
+import jax
+
+CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn the persistent compilation cache on; return its directory."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE))
+    return str(CHECKOUT_CACHE)

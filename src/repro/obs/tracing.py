@@ -29,20 +29,9 @@ import time
 from collections import deque
 from contextlib import contextmanager
 
+from jax.profiler import TraceAnnotation
+
 PHASES = ("schedule", "execute", "router", "io_service", "warp")
-
-_PROFILER = None  # lazily resolved jax.profiler module (or False if absent)
-
-
-def _profiler_mod():
-    global _PROFILER
-    if _PROFILER is None:
-        try:
-            from jax import profiler as _p  # noqa: PLC0415
-            _PROFILER = _p
-        except Exception:
-            _PROFILER = False
-    return _PROFILER
 
 
 class RoundTracer:
@@ -70,23 +59,15 @@ class RoundTracer:
             return
         ann = None
         if self.profiler:
-            mod = _profiler_mod()
-            if mod:
-                try:
-                    ann = mod.TraceAnnotation(f"fleet/{name}")
-                    ann.__enter__()
-                except Exception:
-                    ann = None
+            ann = TraceAnnotation(f"fleet/{name}")
+            ann.__enter__()
         t0 = time.perf_counter()
         try:
             yield
         finally:
             dt = time.perf_counter() - t0
             if ann is not None:
-                try:
-                    ann.__exit__(None, None, None)
-                except Exception:
-                    pass
+                ann.__exit__(None, None, None)
             self.events.append(
                 {"name": name, "round": self.round, "t0": t0 - self.epoch,
                  "dt": dt}

@@ -84,12 +84,14 @@ def test_sharded_lowering_subprocess():
     code = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        os.environ["JAX_PLATFORMS"] = "cpu"
         import sys; sys.path.insert(0, "src")
         import jax
         from repro.config import MeshConfig, RunConfig, ShapeConfig, get_smoke
+        from repro.launch.mesh import make_mesh
         from repro.launch.steps import build_for_shape
         mesh_cfg = MeshConfig(multi_pod=True, pods=2, data=2, model=2)
-        mesh = jax.make_mesh(mesh_cfg.shape, mesh_cfg.axis_names)
+        mesh = make_mesh(mesh_cfg)
         shape = ShapeConfig("t", seq_len=64, global_batch=8, kind="train")
         run = RunConfig(model=get_smoke("glm4-9b"), shape=shape, mesh=mesh_cfg)
         with mesh:

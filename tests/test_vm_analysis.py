@@ -455,8 +455,9 @@ class TestProperties:
             assert analyze_source(prog, CFG).verdict == VERIFIED
             vm = REXAVM(CFG, backend="oracle")
             vm.launch(vm.load(prog))
-            st_a = checked.run_slice(vm.state, steps=256)
-            st_b = elided.run_slice(vm.state, steps=256)
+            st_a, found_a = checked.run_slice(vm.state, steps=256)
+            st_b, found_b = elided.run_slice(vm.state, steps=256)
+            assert bool(found_a) == bool(found_b)
             for f in VMState._fields:
                 assert np.array_equal(
                     np.asarray(getattr(st_a, f)), np.asarray(getattr(st_b, f))

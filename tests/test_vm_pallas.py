@@ -573,6 +573,7 @@ def test_sharded_pallas_ring_subprocess():
     code = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        os.environ["JAX_PLATFORMS"] = "cpu"
         import sys; sys.path.insert(0, "src")
         import numpy as np
         import jax
