@@ -1,0 +1,9 @@
+"""Round-program layer: device milliseconds per round under the
+``fleet.warp`` scope (the virtual-time warp), from the profiler trace of
+the window."""
+
+import fleet_trace
+
+
+def read(ctx):
+    return fleet_trace.phase_per_round(ctx, __file__, "fleet.warp")

@@ -256,13 +256,15 @@ def _build_pallas_engine(
         # before the first unclaimed opcode, so the lax tail continues from
         # an identical intermediate state, and nodes the scheduler left
         # un-woken never satisfy the loops' ST_RUN condition.
-        S, found = jax.vmap(schedule)(S)
-        S, n_exec, bailed, bail_op = fleet_vmloop(
-            S, steps, cfg, isa, mesh=mesh, interpret=interpret,
-            elide_checks=elide_checks,
-        )
-        S = jax.vmap(vmloop_rest)(S, steps - n_exec)
-        S = jax.vmap(preempt)(S)
+        with jax.named_scope("fleet.schedule"):
+            S, found = jax.vmap(schedule)(S)
+        with jax.named_scope("fleet.execute"):
+            S, n_exec, bailed, bail_op = fleet_vmloop(
+                S, steps, cfg, isa, mesh=mesh, interpret=interpret,
+                elide_checks=elide_checks,
+            )
+            S = jax.vmap(vmloop_rest)(S, steps - n_exec)
+            S = jax.vmap(preempt)(S)
         return S, found, n_exec, bailed, bail_op
 
     aux = jax.jit(batched_aux, static_argnames=("steps",))
@@ -281,17 +283,19 @@ def _build_pallas_engine(
         # the task-level counters.  `task`-class words still bail to the
         # tail; the bail lands on the same state under every engine.
         prev = S.cur
-        S, found = jax.vmap(schedule_prio)(S)
-        switched = (found & (S.cur != prev)).astype(jnp.int32)
-        S, n_exec, bailed, bail_op = fleet_vmloop(
-            S, steps, cfg, isa, mesh=mesh, interpret=interpret,
-            elide_checks=elide_checks,
-        )
-        S = jax.vmap(vmloop_rest)(S, steps - n_exec)
-        preempted = jax.vmap(
-            lambda s: (s.tstatus[s.cur] == ST_RUN).astype(jnp.int32)
-        )(S)
-        S = jax.vmap(preempt)(S)
+        with jax.named_scope("fleet.schedule"):
+            S, found = jax.vmap(schedule_prio)(S)
+            switched = (found & (S.cur != prev)).astype(jnp.int32)
+        with jax.named_scope("fleet.execute"):
+            S, n_exec, bailed, bail_op = fleet_vmloop(
+                S, steps, cfg, isa, mesh=mesh, interpret=interpret,
+                elide_checks=elide_checks,
+            )
+            S = jax.vmap(vmloop_rest)(S, steps - n_exec)
+            preempted = jax.vmap(
+                lambda s: (s.tstatus[s.cur] == ST_RUN).astype(jnp.int32)
+            )(S)
+            S = jax.vmap(preempt)(S)
         return S, found, switched, preempted, n_exec, bailed, bail_op
 
     exec_aux = jax.jit(batched_exec_aux, static_argnames=("steps",))

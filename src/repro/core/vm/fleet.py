@@ -179,30 +179,35 @@ class FleetKernels:
         self._constrain = constrain
 
         def warp_fn(S: VMState, progress):
-            # Virtual-time warp to the earliest wake-up (REXAVM.run step 4).
-            runnable = (S.tstatus == ST_YIELD).any(axis=1)
-            iowait = (S.tstatus == ST_IOWAIT).any(axis=1)
-            waiting = (S.tstatus == ST_SLEEP) | (S.tstatus == ST_EVENT)
-            wake = jnp.min(
-                jnp.where(waiting, S.timeout, _I32_MAX), axis=1
-            ).astype(I32)
-            warp = (
-                (~runnable)
-                & (~progress)
-                & (~iowait)
-                & waiting.any(axis=1)
-                & (wake > S.now)
-            )
-            return constrain(S._replace(now=jnp.where(warp, wake, S.now)))
+            with jax.named_scope("fleet.warp"):
+                # Virtual-time warp to the earliest wake-up (REXAVM.run
+                # step 4).
+                runnable = (S.tstatus == ST_YIELD).any(axis=1)
+                iowait = (S.tstatus == ST_IOWAIT).any(axis=1)
+                waiting = (S.tstatus == ST_SLEEP) | (S.tstatus == ST_EVENT)
+                wake = jnp.min(
+                    jnp.where(waiting, S.timeout, _I32_MAX), axis=1
+                ).astype(I32)
+                warp = (
+                    (~runnable)
+                    & (~progress)
+                    & (~iowait)
+                    & waiting.any(axis=1)
+                    & (wake > S.now)
+                )
+                return constrain(S._replace(now=jnp.where(warp, wake, S.now)))
 
         self._warp_fn = warp_fn
 
         def post_slice(S: VMState, steps0):
-            # Virtual clock from the calibrated per-instruction time
-            # (REXAVM.run step 2, per node).
-            inc = jnp.maximum(1, (S.steps - steps0) * cfg.us_per_instr // 1000)
-            S = S._replace(now=S.now + inc)
-            S, progress = route(constrain(S))
+            with jax.named_scope("fleet.router"):
+                # Virtual clock from the calibrated per-instruction time
+                # (REXAVM.run step 2, per node).
+                inc = jnp.maximum(
+                    1, (S.steps - steps0) * cfg.us_per_instr // 1000
+                )
+                S = S._replace(now=S.now + inc)
+                S, progress = route(constrain(S))
             return warp_fn(S, progress)
 
         self._post_slice = post_slice
@@ -243,11 +248,12 @@ class FleetKernels:
                 S = constrain(S)
                 steps0 = S.steps
                 S, _, n_exec, bailed, bail_op = aux_slice(S, steps)
-                # Per-opcode bail histogram: non-bailed nodes carry
-                # bail_op == -1 and add 0 (clipped to slot 0).
-                hist = jnp.zeros(nops + 1, I32).at[
-                    jnp.clip(bail_op, 0, nops)
-                ].add(bailed.astype(I32))
+                with jax.named_scope("fleet.execute"):
+                    # Per-opcode bail histogram: non-bailed nodes carry
+                    # bail_op == -1 and add 0 (clipped to slot 0).
+                    hist = jnp.zeros(nops + 1, I32).at[
+                        jnp.clip(bail_op, 0, nops)
+                    ].add(bailed.astype(I32))
                 return post_slice(S, steps0), n_exec, bailed, hist
 
             self.round_aux = jax.jit(round_body, static_argnames=("steps",))
@@ -330,9 +336,10 @@ class FleetKernels:
 
             def sub_slice(S: VMState):
                 S, _, sw, pe, n_exec, bailed, bail_op = exec_aux(S, q)
-                hist = jnp.zeros(nops + 1, I32).at[
-                    jnp.clip(bail_op, 0, nops)
-                ].add(bailed.astype(I32))
+                with jax.named_scope("fleet.execute"):
+                    hist = jnp.zeros(nops + 1, I32).at[
+                        jnp.clip(bail_op, 0, nops)
+                    ].add(bailed.astype(I32))
                 return (
                     S,
                     sw.sum(),
@@ -401,11 +408,12 @@ class FleetKernels:
             route_obs = build_router(cfg, self.isa, obs=True)
 
             def clock_route(S: VMState, steps0):
-                inc = jnp.maximum(
-                    1, (S.steps - steps0) * cfg.us_per_instr // 1000
-                )
-                S = S._replace(now=S.now + inc)
-                S, progress, (drops, depth) = route_obs(constrain(S))
+                with jax.named_scope("fleet.router"):
+                    inc = jnp.maximum(
+                        1, (S.steps - steps0) * cfg.us_per_instr // 1000
+                    )
+                    S = S._replace(now=S.now + inc)
+                    S, progress, (drops, depth) = route_obs(constrain(S))
                 return S, inc, drops, depth, progress
 
             def accum(acc: ObsCounters, aux, inc, drops, depth, deadline_ms):
@@ -681,24 +689,24 @@ class FleetVM:
         )
         self._task_deadline_miss_total = 0
         # Observability plane (repro.obs): fully off by default — no extra
-        # device outputs, no per-phase syncs, nothing accumulated.
+        # device outputs, no per-phase syncs, nothing accumulated.  The
+        # span tracer always annotates the profiler; ObsConfig(trace=True)
+        # adds its ring.
         from repro.obs.metrics import normalize_obs
+        from repro.obs.tracing import RoundTracer
         self.obs = normalize_obs(obs)
         self._counters = None              # device ObsCounters (lazy adds)
-        self._tracer = None
         self._deadline = None
+        self._tracer = (
+            RoundTracer(ring=self.obs.trace_ring, enabled=self.obs.trace)
+            if self.obs is not None else RoundTracer(enabled=False)
+        )
+        self.io_service.tracer = self._tracer
         if self.obs is not None:
             from repro.obs.deadline import DeadlineMonitor
             from repro.obs.metrics import zero_counters
-            from repro.obs.tracing import RoundTracer
             self._counters = zero_counters(self.n, isa)
-            self._tracer = RoundTracer(
-                ring=self.obs.trace_ring,
-                enabled=self.obs.trace,
-                profiler=self.obs.profiler,
-            )
             self._deadline = DeadlineMonitor(self.obs.deadline_wall_ms)
-            self.io_service.tracer = self._tracer
             # Attach the executor's counting engine (a no-op if another
             # fleet sharing these cached kernels already did).
             self.kernels.executor.ensure_obs()
@@ -908,10 +916,9 @@ class FleetVM:
         (open in chrome://tracing or ui.perfetto.dev).  Requires
         ``obs=ObsConfig(trace=True)``; without it the export is valid but
         empty.  Returns the payload dict."""
-        from repro.obs.tracing import RoundTracer, export_chrome_trace
+        from repro.obs.tracing import export_chrome_trace
 
-        tracer = self._tracer or RoundTracer(enabled=False)
-        return export_chrome_trace(tracer, path)
+        return export_chrome_trace(self._tracer, path)
 
     # -- static analysis (the Auditor) -----------------------------------------
 
@@ -942,41 +949,51 @@ class FleetVM:
         reflects the program set about to execute.  Programs with verifier
         errors are *not* rejected here (the CLI gate is the reject path);
         they run on the always-checked batched engine.
+
+        Spanned as ``fleet.audit`` with the nodes verified, the plan, and
+        ``switched`` (the kernels were swapped, so the next dispatch traces
+        and compiles).
         """
         from repro.analysis.feasibility import plan_backend, predict_branch_sets
 
-        reports = self._analyze_nodes()
-        branch_sets = []      # per node: the entry-trace compile key
-        aot_sets = []         # every set the engine will record (entry +
-        #                       steady-state loop re-entries, any rotation)
-        for vm, rep in zip(self.nodes, reports):
-            entry = rep.entries[0].pc if rep.entries else None
-            sets = (
-                predict_branch_sets(vm.state.cs, entry, vm.isa)
-                if entry is not None else ()
+        with self._tracer.span("audit", nodes=self.n) as sp:
+            reports = self._analyze_nodes()
+            branch_sets = []      # per node: the entry-trace compile key
+            aot_sets = []         # every set the engine will record (entry +
+            #                       steady-state loop re-entries, any rotation)
+            for vm, rep in zip(self.nodes, reports):
+                entry = rep.entries[0].pc if rep.entries else None
+                sets = (
+                    predict_branch_sets(vm.state.cs, entry, vm.isa)
+                    if entry is not None else ()
+                )
+                branch_sets.append(sets[0] if sets else None)
+                aot_sets.extend(sets)
+            plan = plan_backend(reports, branch_sets)
+            self._node_reports = reports
+            self._analysis = plan
+            switched = (plan.executor, plan.elide_checks) != (
+                self.executor_kind, self._elide
             )
-            branch_sets.append(sets[0] if sets else None)
-            aot_sets.extend(sets)
-        plan = plan_backend(reports, branch_sets)
-        self._node_reports = reports
-        self._analysis = plan
-        if (plan.executor, plan.elide_checks) != (
-            self.executor_kind, self._elide
-        ):
-            self.kernels = self._make_kernels(plan.executor, plan.elide_checks)
-            self.executor_kind = plan.executor
-            self._elide = plan.elide_checks
-            if self.obs is not None:
-                self.kernels.executor.ensure_obs()
-            if plan.executor == "trace" and self._trace0 is None:
-                self._trace0 = self.kernels.executor.stats()
-        if plan.executor == "trace":
-            # AOT: compile each predicted branch set now, so the first
-            # slice dispatches a warm specialized trace (traces_compiled
-            # stops moving during run — the equivalence tests assert it).
-            eng = self.kernels.executor.engine
-            for bs in aot_sets:
-                eng.fn_for(bs)
+            if switched:
+                self.kernels = self._make_kernels(
+                    plan.executor, plan.elide_checks
+                )
+                self.executor_kind = plan.executor
+                self._elide = plan.elide_checks
+                if self.obs is not None:
+                    self.kernels.executor.ensure_obs()
+                if plan.executor == "trace" and self._trace0 is None:
+                    self._trace0 = self.kernels.executor.stats()
+            if plan.executor == "trace":
+                # AOT: compile each predicted branch set now, so the first
+                # slice dispatches a warm specialized trace (traces_compiled
+                # stops moving during run — the equivalence tests assert it).
+                eng = self.kernels.executor.engine
+                for bs in aot_sets:
+                    eng.fn_for(bs)
+            sp.set(executor=self.executor_kind, elide_checks=self._elide,
+                   switched=switched)
 
     def analysis_stats(self) -> dict:
         """Auditor telemetry, schema-stable like the other stats planes.
@@ -1022,40 +1039,53 @@ class FleetVM:
         """Stack per-node host states into the device-resident fleet state
         (sharded over the node mesh axis when a mesh was given).  Under
         ``executor="auto"`` the Auditor runs first: verify the loaded
-        programs, resolve the backend, and AOT-compile predicted traces."""
+        programs, resolve the backend, and AOT-compile predicted traces.
+
+        Starts a new read-out id; spanned as ``fleet.start`` around
+        ``fleet.audit`` and ``fleet.restack``."""
         from repro.core.vm.vmstate import stack_states
 
-        if self._auto:
-            self._resolve_auto()
-        stacked = stack_states([vm.state for vm in self.nodes])
-        if self._sharding is not None:
-            self._S = VMState(
-                *[jax.device_put(x, self._sharding) for x in stacked]
-            )
-        else:
-            self._S = VMState(*[jnp.asarray(x) for x in stacked])
-        self.h2d += 1
-        self.h2d_bytes += vms.state_nbytes(stacked)
-        if self.executor_kind == "trace":
-            # Refresh the green keys: push()/start() is exactly when host-
-            # side recompiles or incremental code loads land, and a changed
-            # code segment must re-key (content hash) its trace-cache
-            # entries.  Stale keys would still be byte-safe (per-step CS
-            # guards), just slower.
-            from repro.core.vm.trace import program_key
-            self.kernels.executor.set_program_keys(
-                [program_key(vm.state.cs) for vm in self.nodes]
-            )
+        tr = self._tracer
+        tr.next_readout()
+        with tr.span("start"):
+            if self._auto:
+                self._resolve_auto()
+            with tr.span("restack") as sp:
+                stacked = stack_states([vm.state for vm in self.nodes])
+                if self._sharding is not None:
+                    self._S = VMState(
+                        *[jax.device_put(x, self._sharding)
+                          for x in stacked]
+                    )
+                else:
+                    self._S = VMState(*[jnp.asarray(x) for x in stacked])
+                self.h2d += 1
+                self.h2d_bytes += vms.state_nbytes(stacked)
+                if self.executor_kind == "trace":
+                    # Refresh the green keys: push()/start() is exactly when
+                    # host-side recompiles or incremental code loads land,
+                    # and a changed code segment must re-key (content hash)
+                    # its trace-cache entries.  Stale keys would still be
+                    # byte-safe (per-step CS guards), just slower.
+                    from repro.core.vm.trace import program_key
+                    self.kernels.executor.set_program_keys(
+                        [program_key(vm.state.cs) for vm in self.nodes]
+                    )
+                sp.set(bytes=vms.state_nbytes(self._S))
 
     def sync(self) -> None:
-        """Pull the stacked state back into the per-node host frontends."""
+        """Pull the stacked state back into the per-node host frontends
+        (spanned as ``fleet.sync`` with the bytes copied)."""
         assert self._S is not None, "fleet not started"
-        host = [np.array(x) for x in self._S]
-        for i, vm in enumerate(self.nodes):
-            # np.array keeps 0-d fields as mutable 0-d arrays, not scalars.
-            vm.state = VMState(*[np.array(f[i]) for f in host])
+        nbytes = vms.state_nbytes(self._S)
+        with self._tracer.span("sync", bytes=nbytes):
+            host = [np.array(x) for x in self._S]
+            for i, vm in enumerate(self.nodes):
+                # np.array keeps 0-d fields as mutable 0-d arrays, not
+                # scalars.
+                vm.state = VMState(*[np.array(f[i]) for f in host])
         self.d2h += 1
-        self.d2h_bytes += vms.state_nbytes(self._S)
+        self.d2h_bytes += nbytes
 
     def push(self) -> None:
         """Re-stack (possibly host-mutated) node states onto the device."""
@@ -1064,20 +1094,23 @@ class FleetVM:
     # -- execution -------------------------------------------------------------
 
     def _probe(self):
-        """Cheap device->host peek at scheduler-visible state (not a full sync)."""
+        """Cheap device->host peek at scheduler-visible state (not a full
+        sync); spanned as ``fleet.probe``, which waits for the rounds
+        dispatched before it."""
         self.probes += 1
         # One batched fetch: separate np.asarray calls would each block on
         # their own device round trip.  now/deadline ride along for the
         # Executive's deadline-miss accounting (same rows, negligible bytes).
-        return jax.device_get(
-            (
-                self._S.tstatus,
-                self._S.io_op,
-                self._S.steps,
-                self._S.now,
-                self._S.deadline,
+        with self._tracer.span("probe"):
+            return jax.device_get(
+                (
+                    self._S.tstatus,
+                    self._S.io_op,
+                    self._S.steps,
+                    self._S.now,
+                    self._S.deadline,
+                )
             )
-        )
 
     def _service_host_io(self, node_mask: np.ndarray) -> bool:
         """Service host-IO suspensions of the masked nodes.
@@ -1164,7 +1197,6 @@ class FleetVM:
         if timing:
             jax.block_until_ready(self._S)
             self._deadline.record((_time.perf_counter() - t0) * 1e3)
-        tr.tick()
 
     def run(
         self,
@@ -1181,113 +1213,136 @@ class FleetVM:
         round mode: chunks of ``service_every`` whole rounds (kernel slice +
         collective router + warp each) run as one compiled
         ``FleetKernels.rounds_aux`` loop between host probes.
+
+        Spanned as ``fleet.run``; each round's ``fleet.dispatch`` span
+        covers only enqueueing it (JAX dispatch is async; the observed path
+        with span tracing or round timing on syncs).  The body stays in
+        this frame: JAX traces the round program from here, and on a TPU
+        v5e host a deeper Python stack above that call made the trace
+        measurably slower (not so on a CPU host).
         """
         steps = steps or self.cfg.steps_per_slice
         if self._S is None:
             self.start()
-        steps0 = np.asarray(self._S.steps).copy()
-        rounds = 0
-        stall = 0
-        last_steps_sum = -1
-        round_aux = self.kernels.round_aux
-        rounds_aux = self.kernels.rounds_aux
-        round_exec = self.kernels.round_exec if self.executive else None
-        while rounds < max_rounds:
-            if self.obs is not None:
-                # Observed rounds run phased (counters, spans, deadlines);
-                # message-bound chunking is bypassed so every round is
-                # individually accounted.
-                self._round_obs(steps)
-                rounds += 1
-            elif round_exec is not None:
-                # Executive round: ExecutiveConfig.slices preemptive
-                # micro-slices of .quantum instructions (priority schedule
-                # per sub-slice), clock/router/warp once.  Task/kernel
-                # telemetry accumulates lazily on device.
-                self._S, sw, pe, ne, bl, hist = round_exec(self._S)
-                self._task_switches_acc = self._task_switches_acc + sw
-                self._preempts_acc = self._preempts_acc + pe
-                self._kernel_steps_acc = self._kernel_steps_acc + ne
-                self._bailed_acc = self._bailed_acc + bl
-                self._bail_hist_acc = self._bail_hist_acc + hist
-                self._exec_slices += self.executive.slices
-                rounds += 1
-            elif rounds_aux is not None and service_every > 1:
-                # Message-bound round mode: probe only at chunk boundaries.
-                chunk = min(service_every, max_rounds - rounds)
-                self._S, n_sum, b_sum, hist = rounds_aux(self._S, steps, chunk)
-                self._kernel_steps_acc = self._kernel_steps_acc + n_sum
-                self._bailed_acc = self._bailed_acc + b_sum
-                self._bail_hist_acc = self._bail_hist_acc + hist
-                rounds += chunk
-            elif round_aux is not None:
-                self._S, n_exec, bailed, hist = round_aux(self._S, steps)
-                # Lazy device-side sums: no sync until pallas_stats().
-                self._kernel_steps_acc = self._kernel_steps_acc + n_exec.sum()
-                self._bailed_acc = self._bailed_acc + bailed.sum()
-                self._bail_hist_acc = self._bail_hist_acc + hist
-                rounds += 1
-            else:
-                self._S = self.kernels.round(self._S, steps)
-                rounds += 1
-            if rounds % service_every != 0 and rounds < max_rounds:
-                continue
-            tstatus, io_op, steps_now, now_v, deadline_v = self._probe()
-            if self.executive is not None:
-                # Task-level deadline misses: a live slot whose virtual
-                # clock has run past its (nonzero) deadline.  The sticky
-                # flag counts each slot occupancy's first miss once and
-                # clears when the slot frees.
-                active = tstatus != ST_FREE
-                missed_now = (
-                    (deadline_v > 0) & (now_v[:, None] > deadline_v) & active
+        tr = self._tracer
+        with tr.span("run") as sp:
+            steps0 = np.asarray(self._S.steps).copy()
+            rounds = 0
+            stall = 0
+            last_steps_sum = -1
+            round_aux = self.kernels.round_aux
+            rounds_aux = self.kernels.rounds_aux
+            round_exec = self.kernels.round_exec if self.executive else None
+            while rounds < max_rounds:
+                done = rounds
+                with tr.span("dispatch"):
+                    if self.obs is not None:
+                        # Observed rounds run phased (counters, spans,
+                        # deadlines); message-bound chunking is bypassed so
+                        # every round is individually accounted.
+                        self._round_obs(steps)
+                        rounds += 1
+                    elif round_exec is not None:
+                        # Executive round: ExecutiveConfig.slices preemptive
+                        # micro-slices of .quantum instructions (priority
+                        # schedule per sub-slice), clock/router/warp once.
+                        # Task/kernel telemetry accumulates lazily on device.
+                        self._S, sw, pe, ne, bl, hist = round_exec(self._S)
+                        self._task_switches_acc = self._task_switches_acc + sw
+                        self._preempts_acc = self._preempts_acc + pe
+                        self._kernel_steps_acc = self._kernel_steps_acc + ne
+                        self._bailed_acc = self._bailed_acc + bl
+                        self._bail_hist_acc = self._bail_hist_acc + hist
+                        self._exec_slices += self.executive.slices
+                        rounds += 1
+                    elif rounds_aux is not None and service_every > 1:
+                        # Message-bound round mode: probe only at chunk
+                        # boundaries.
+                        chunk = min(service_every, max_rounds - rounds)
+                        self._S, n_sum, b_sum, hist = rounds_aux(
+                            self._S, steps, chunk
+                        )
+                        self._kernel_steps_acc = self._kernel_steps_acc + n_sum
+                        self._bailed_acc = self._bailed_acc + b_sum
+                        self._bail_hist_acc = self._bail_hist_acc + hist
+                        rounds += chunk
+                    elif round_aux is not None:
+                        self._S, n_exec, bailed, hist = round_aux(self._S, steps)
+                        # Lazy device-side sums: no sync until pallas_stats().
+                        self._kernel_steps_acc = (
+                            self._kernel_steps_acc + n_exec.sum()
+                        )
+                        self._bailed_acc = self._bailed_acc + bailed.sum()
+                        self._bail_hist_acc = self._bail_hist_acc + hist
+                        rounds += 1
+                    else:
+                        self._S = self.kernels.round(self._S, steps)
+                        rounds += 1
+                tr.tick(rounds - done)
+                if rounds % service_every != 0 and rounds < max_rounds:
+                    continue
+                tstatus, io_op, steps_now, now_v, deadline_v = self._probe()
+                if self.executive is not None:
+                    # Task-level deadline misses: a live slot whose virtual
+                    # clock has run past its (nonzero) deadline.  The sticky
+                    # flag counts each slot occupancy's first miss once and
+                    # clears when the slot frees.
+                    active = tstatus != ST_FREE
+                    missed_now = (
+                        (deadline_v > 0) & (now_v[:, None] > deadline_v) & active
+                    )
+                    self._task_deadline_miss_total += int(
+                        (missed_now & ~self._deadline_missed).sum()
+                    )
+                    self._deadline_missed = (
+                        self._deadline_missed | missed_now
+                    ) & active
+                host_io = (
+                    (tstatus == ST_IOWAIT)
+                    & (io_op != 0)
+                    & (io_op != self._op_send)
+                    & (io_op != self._op_recv)
                 )
-                self._task_deadline_miss_total += int(
-                    (missed_now & ~self._deadline_missed).sum()
+                serviced = False
+                if host_io.any():
+                    serviced = self._service_host_io(host_io.any(axis=1))
+                # A node is finished only when task 0 is terminal AND no other
+                # task is runnable, waiting, or IO-suspended (REXAVM.run's
+                # "done" condition) — background workers keep the fleet alive.
+                task0_term = np.isin(tstatus[:, 0], (ST_DONE, ST_HALT, ST_ERR))
+                runnable = (tstatus == ST_YIELD).any(axis=1)
+                waiting = np.isin(tstatus, (ST_SLEEP, ST_EVENT)).any(axis=1)
+                iowait = (tstatus == ST_IOWAIT).any(axis=1)
+                if (task0_term & ~runnable & ~waiting & ~iowait).all():
+                    break
+                steps_sum = int(steps_now.sum())
+                if steps_sum == last_steps_sum and not serviced:
+                    stall += 1
+                    if stall >= 3:
+                        break              # fleet-wide deadlock / quiescence
+                else:
+                    stall = 0
+                last_steps_sum = steps_sum
+            self.sync()
+            with tr.span("decode", nodes=self.n):
+                self.rounds_total += rounds
+                executed = np.asarray(self._S.steps) - steps0
+                self._trace_steps_total += int(executed.sum())
+                self._total_steps_acc += int(executed.sum())
+                # Host frontends are canonical again; a later run() restacks
+                # them.
+                self._S = None
+                task0 = np.asarray(
+                    [int(vm.state.tstatus[0]) for vm in self.nodes]
                 )
-                self._deadline_missed = (
-                    self._deadline_missed | missed_now
-                ) & active
-            host_io = (
-                (tstatus == ST_IOWAIT)
-                & (io_op != 0)
-                & (io_op != self._op_send)
-                & (io_op != self._op_recv)
-            )
-            serviced = False
-            if host_io.any():
-                serviced = self._service_host_io(host_io.any(axis=1))
-            # A node is finished only when task 0 is terminal AND no other
-            # task is runnable, waiting, or IO-suspended (REXAVM.run's
-            # "done" condition) — background workers keep the fleet alive.
-            task0_term = np.isin(tstatus[:, 0], (ST_DONE, ST_HALT, ST_ERR))
-            runnable = (tstatus == ST_YIELD).any(axis=1)
-            waiting = np.isin(tstatus, (ST_SLEEP, ST_EVENT)).any(axis=1)
-            iowait = (tstatus == ST_IOWAIT).any(axis=1)
-            if (task0_term & ~runnable & ~waiting & ~iowait).all():
-                break
-            steps_sum = int(steps_now.sum())
-            if steps_sum == last_steps_sum and not serviced:
-                stall += 1
-                if stall >= 3:
-                    break              # fleet-wide deadlock / quiescence
-            else:
-                stall = 0
-            last_steps_sum = steps_sum
-        self.sync()
-        self.rounds_total += rounds
-        executed = np.asarray(self._S.steps) - steps0
-        self._trace_steps_total += int(executed.sum())
-        self._total_steps_acc += int(executed.sum())
-        # Host frontends are canonical again; a later run() restacks them.
-        self._S = None
-        task0 = np.asarray([int(vm.state.tstatus[0]) for vm in self.nodes])
-        return FleetResult(
-            rounds=rounds,
-            steps=executed,
-            statuses=[_STATUS_NAME.get(s, "running") for s in task0],
-            outputs=[vm.output() for vm in self.nodes],
-        )
+                res = FleetResult(
+                    rounds=rounds,
+                    steps=executed,
+                    statuses=[_STATUS_NAME.get(s, "running") for s in task0],
+                    outputs=[vm.output() for vm in self.nodes],
+                )
+            sp.set(rounds=rounds)
+        return res
 
 
 # ---------------------------------------------------------------------------

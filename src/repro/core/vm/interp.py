@@ -1106,16 +1106,22 @@ class Interpreter:
 
         def run_slice(st: VMState, steps: int):
             """schedule -> vmloop -> preempt (one Fig. 10 service round)."""
-            st, found = schedule(st)
-            st = lax.cond(found, lambda s: vmloop(s, steps), lambda s: s, st)
-            # Preempt a task that exhausted its slice (stays ready).
-            still_running = st.tstatus[st.cur] == ST_RUN
-            st = lax.cond(
-                still_running,
-                lambda s: s._replace(tstatus=s.tstatus.at[s.cur].set(ST_YIELD)),
-                lambda s: s,
-                st,
-            )
+            with jax.named_scope("fleet.schedule"):
+                st, found = schedule(st)
+            with jax.named_scope("fleet.execute"):
+                st = lax.cond(
+                    found, lambda s: vmloop(s, steps), lambda s: s, st
+                )
+                # Preempt a task that exhausted its slice (stays ready).
+                still_running = st.tstatus[st.cur] == ST_RUN
+                st = lax.cond(
+                    still_running,
+                    lambda s: s._replace(
+                        tstatus=s.tstatus.at[s.cur].set(ST_YIELD)
+                    ),
+                    lambda s: s,
+                    st,
+                )
             return st, found
 
         self._run_slice = run_slice
@@ -1187,16 +1193,22 @@ class Interpreter:
             ``preempted`` is 1 when the task was still ST_RUN at quantum end.
             """
             prev = st.cur
-            st, found = schedule_prio(st)
-            switched = (found & (st.cur != prev)).astype(I32)
-            st = lax.cond(found, lambda s: vmloop(s, steps), lambda s: s, st)
-            preempted = st.tstatus[st.cur] == ST_RUN
-            st = lax.cond(
-                preempted,
-                lambda s: s._replace(tstatus=s.tstatus.at[s.cur].set(ST_YIELD)),
-                lambda s: s,
-                st,
-            )
+            with jax.named_scope("fleet.schedule"):
+                st, found = schedule_prio(st)
+                switched = (found & (st.cur != prev)).astype(I32)
+            with jax.named_scope("fleet.execute"):
+                st = lax.cond(
+                    found, lambda s: vmloop(s, steps), lambda s: s, st
+                )
+                preempted = st.tstatus[st.cur] == ST_RUN
+                st = lax.cond(
+                    preempted,
+                    lambda s: s._replace(
+                        tstatus=s.tstatus.at[s.cur].set(ST_YIELD)
+                    ),
+                    lambda s: s,
+                    st,
+                )
             return st, found, switched, preempted.astype(I32)
 
         self.run_slice_exec_fn = run_slice_exec

@@ -166,16 +166,17 @@ class FleetIOService:
         self.nodes_serviced = 0      # node-slices moved (both directions)
         self.d2h_bytes = 0
         self.h2d_bytes = 0
-        self.tracer = None           # optional repro.obs.RoundTracer
+        self.tracer = None           # the fleet's repro.obs.RoundTracer
 
     def service(self, S, node_idx) -> tuple[object, bool]:
         """Service host-IO suspensions of ``node_idx`` against device state
-        ``S`` (a stacked fleet ``VMState``).  Returns ``(S', progress)``."""
-        tr = self.tracer
-        if tr is not None and tr.enabled:
-            with tr.span("io_service"):
-                return self._service(S, node_idx)
-        return self._service(S, node_idx)
+        ``S`` (a stacked fleet ``VMState``).  Returns ``(S', progress)``.
+        Spanned as ``fleet.io_service`` with the suspended nodes' count
+        when the fleet has attached its tracer."""
+        if self.tracer is None:
+            return self._service(S, node_idx)
+        with self.tracer.span("io_service", nodes=len(node_idx)):
+            return self._service(S, node_idx)
 
     def _service(self, S, node_idx) -> tuple[object, bool]:
         import jax

@@ -73,12 +73,18 @@ def vmloop_call(
     per-step stack pre-check disappears from the kernel body at build time
     (see ``ref.make_core_step``) — sound only when every program in the
     fleet passed the static verifier.
+
+    Building the rows is named ``vmloop.pack`` and cutting them apart
+    ``vmloop.unpack`` (``jax.named_scope``); the kernel keeps the name
+    ``vmloop``.
     """
     isa = isa or get_isa()
     N = core.pc.shape[0]
     lay = Layout(cfg, isa)
     run_core = make_run_core(cfg, isa, obs=obs, elide_checks=elide_checks)
     tab = jnp.asarray(flat_tables(isa)[0])
+    with jax.named_scope("vmloop.pack"):
+        packed = lay.pack(core)
 
     def kernel(tab_ref, rows_in, rows_out, row):
         i = pl.program_id(0)
@@ -102,9 +108,10 @@ def vmloop_call(
         ),
         interpret=interpret,
         name="vmloop",
-    )(tab, lay.pack(core))
+    )(tab, packed)
 
-    core, n_exec, bailed, bail_op, op_hist = lay.unpack(rows)
+    with jax.named_scope("vmloop.unpack"):
+        core, n_exec, bailed, bail_op, op_hist = lay.unpack(rows)
     if obs:
         return core, n_exec, bailed, bail_op, op_hist
     return core, n_exec, bailed, bail_op

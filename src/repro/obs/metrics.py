@@ -59,8 +59,10 @@ EXTRA_BINS = ("fios/trap", "lit", "call", "invalid")
 class ObsConfig:
     """Observability switchboard (hashable: joins jit/engine cache keys).
 
-    ``trace``            — record round-phase spans (adds one device sync
-                           per phase so span walls are honest);
+    ``trace``            — keep the fleet's spans in a host ring for
+                           ``export_trace()`` and add the round-phase spans
+                           (one device sync per phase so their walls are
+                           honest);
     ``trace_ring``       — host ring-buffer capacity in span events;
     ``deadline_ms``      — virtual-clock round deadline: a node misses when
                            its per-round clock increment exceeds this many
@@ -69,9 +71,10 @@ class ObsConfig:
     ``deadline_wall_ms`` — wall-clock round deadline for the host latency
                            monitor (0 disables);
     ``time_rounds``      — feed the wall-clock latency histogram (one
-                           ``block_until_ready`` per round);
-    ``profiler``         — wrap spans in ``jax.profiler.TraceAnnotation``
-                           so device profiles carry the phase names.
+                           ``block_until_ready`` per round).
+
+    The fleet's spans reach any ``jax.profiler`` trace whether or not
+    ``trace`` is set (``repro.obs.tracing``).
     """
 
     trace: bool = False
@@ -79,7 +82,6 @@ class ObsConfig:
     deadline_ms: int = 0
     deadline_wall_ms: float = 0.0
     time_rounds: bool = True
-    profiler: bool = False
 
 
 def normalize_obs(obs) -> ObsConfig | None:

@@ -271,7 +271,9 @@ def test_deadline_misses_deterministic_across_executors():
 
 def test_trace_export_one_span_per_phase_per_round(ring_metrics, tmp_path):
     """export_trace() emits valid Chrome trace-event JSON with exactly one
-    schedule/execute/router/warp span per observed round."""
+    schedule/execute/router/warp span per observed round, each inside that
+    round's dispatch span, and the fleet's host spans: one start (with its
+    restack), run, sync and decode per read-out, one probe per probe."""
     for executor, (fleet, res, m) in ring_metrics.items():
         path = tmp_path / f"trace_{executor}.json"
         payload = fleet.export_trace(str(path))
@@ -279,13 +281,25 @@ def test_trace_export_one_span_per_phase_per_round(ring_metrics, tmp_path):
         assert validate_chrome_trace(str(path)) == n_spans
         rounds = m.as_dict()["counters"]["rounds_observed"]
         by_name: dict = {}
+        by_id = {}
         for ev in payload["traceEvents"]:
             if ev.get("ph") == "X":
                 by_name[ev["name"]] = by_name.get(ev["name"], 0) + 1
+                by_id[ev["args"]["id"]] = ev
                 assert ev["dur"] >= 0
                 assert "round" in ev["args"]
-        for phase in ("schedule", "execute", "router", "warp"):
+                assert ev["args"]["readout"] == 1
+        for phase in ("schedule", "execute", "router", "warp", "dispatch"):
             assert by_name.get(phase, 0) == rounds, (executor, phase, by_name)
+        for name in ("start", "restack", "run", "sync", "decode"):
+            assert by_name.get(name, 0) == 1, (executor, name, by_name)
+        assert by_name["probe"] == fleet.transfer_stats()["probes"]
+        for ev in by_id.values():
+            parent = by_id.get(ev["args"]["parent"], {}).get("name")
+            if ev["name"] in ("schedule", "execute", "router", "warp"):
+                assert parent == "dispatch", (executor, ev)
+            elif ev["name"] in ("dispatch", "probe", "sync", "decode"):
+                assert parent == "run", (executor, ev)
 
 
 def test_validate_chrome_trace_rejects_garbage(tmp_path):
