@@ -103,16 +103,21 @@ class Instr:
         return self.name
 
 
-def decode(cs: np.ndarray, pc: int, isa: ISA | None = None) -> Instr:
+def decode(
+    cs: np.ndarray, pc: int, isa: ISA | None = None, reads: set | None = None
+) -> Instr:
     """Decode the instruction at ``pc`` from a host code-segment array.
 
     ``pc`` must be in bounds (the caller checks — an out-of-bounds pc is a
     *control-flow* diagnostic, not a decode error).  Operand cells past the
     end of CS decode as ``None`` (the verifier turns that into an error).
+    ``reads``, when given, collects the address of every cell read.
     """
     isa = isa or get_isa()
     n = len(cs)
     cell = int(np.int32(cs[pc]))
+    if reads is not None:
+        reads.add(pc)
     tag = cell & 3
     if tag == TAG_CALL:
         payload = (cell & 0xFFFFFFFF) >> 2
@@ -123,7 +128,11 @@ def decode(cs: np.ndarray, pc: int, isa: ISA | None = None) -> Instr:
     eff = min(max(payload, 0), isa.num_ops)
     name = isa.name[eff] if eff < isa.num_ops else None
     if name in OPERAND_WORDS:
-        operand = int(np.int32(cs[pc + 1])) if pc + 1 < n else None
+        operand = None
+        if pc + 1 < n:
+            operand = int(np.int32(cs[pc + 1]))
+            if reads is not None:
+                reads.add(pc + 1)
         size = 2
         if name == "prstr":
             size = 2 + min(max(operand or 0, 0), PRSTR_MAX)

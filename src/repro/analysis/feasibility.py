@@ -63,7 +63,8 @@ def predict_branch_set(
 
 
 def predict_branch_sets(
-    cs, entry: int, isa: ISA | None = None, cap: int = 128
+    cs, entry: int, isa: ISA | None = None, cap: int = 128,
+    reads: set | None = None,
 ) -> tuple:
     """All branch sets the trace engine will ever record for this program:
     the entry trace plus the steady-state loop trace.
@@ -72,21 +73,22 @@ def predict_branch_sets(
     loop; every rotation of the cycle records the same instruction set, so
     one extra walk from the first revisited pc (the loop head, with no
     entry preamble) covers all of them.  Returns ``()`` when the entry
-    path itself is unpredictable.
+    path itself is unpredictable.  ``reads``, when given, collects the
+    address of every code cell the walks decoded.
     """
-    first, loop_pc = _walk(cs, entry, isa, cap)
+    first, loop_pc = _walk(cs, entry, isa, cap, reads)
     if first is None:
         return ()
     sets = [first]
     if loop_pc is not None:
-        steady, _ = _walk(cs, loop_pc, isa, cap)
+        steady, _ = _walk(cs, loop_pc, isa, cap, reads)
         if steady is not None and steady != first:
             sets.append(steady)
     return tuple(sets)
 
 
 def _walk(
-    cs, entry: int, isa: ISA | None, cap: int
+    cs, entry: int, isa: ISA | None, cap: int, reads: set | None = None
 ) -> tuple[tuple | None, int | None]:
     """Recorder-walk core: ``(branch_set | None, revisited_pc | None)``."""
     isa = isa or get_isa()
@@ -114,7 +116,7 @@ def _walk(
             loop_pc = pc
             break
         seen.add(pc)
-        ins = decode(cs, pc, isa)
+        ins = decode(cs, pc, isa, reads)
         kinds.append(ins.trace_kind(num_ops))
         if ins.is_lit:
             ds.append(ins.payload)

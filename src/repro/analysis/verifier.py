@@ -180,8 +180,9 @@ def _join(a: _Abs, b: _Abs) -> _Abs:
 class _Analyzer:
     """Shared analysis context over one code segment."""
 
-    def __init__(self, cs, isa, vmcfg, fios_effects, symbols):
+    def __init__(self, cs, isa, vmcfg, fios_effects, symbols, reads=None):
         self.cs = np.asarray(cs)
+        self.reads = reads             # set of cell addresses decoded, or None
         self.isa = isa
         self.vmcfg = vmcfg
         self.fios_effects = fios_effects or {}
@@ -197,7 +198,7 @@ class _Analyzer:
     def decode(self, pc: int) -> Instr:
         ins = self._decoded.get(pc)
         if ins is None:
-            ins = decode(self.cs, pc, self.isa)
+            ins = decode(self.cs, pc, self.isa, self.reads)
             self._decoded[pc] = ins
         return ins
 
@@ -641,16 +642,19 @@ def analyze_program(
     *,
     fios_effects=None,
     symbols=None,
+    reads: set | None = None,
 ) -> ProgramReport:
     """Verify a code segment from a set of task entries.
 
     ``entries`` is a list of pcs or ``(pc, dsp, fsp, rsp, rs0)`` tuples.
     Constant ``task`` spawn targets discovered during the walk are verified
     as additional entries (with the in-VM spawn register state).
+    ``reads``, when given, collects the address of every code cell the
+    analysis decoded: the verdict depends on ``cs`` through those alone.
     """
     isa = isa or get_isa()
     vmcfg = vmcfg or VMConfig()
-    ctx = _Analyzer(np.asarray(cs), isa, vmcfg, fios_effects, symbols)
+    ctx = _Analyzer(np.asarray(cs), isa, vmcfg, fios_effects, symbols, reads)
     todo = []
     for e in entries:
         todo.append(tuple(e) if isinstance(e, (tuple, list)) else (int(e), 0, 0, 0, 0))
@@ -694,22 +698,25 @@ def analyze_program(
     )
 
 
-def analyze_vm(vm, entries=None) -> ProgramReport:
-    """Verify a host :class:`~repro.core.vm.machine.REXAVM`'s current code
-    segment from its live task entries (or explicit ``entries``), with the
-    node's syscall arities and dictionary names feeding the analysis."""
+def live_entries(st) -> list:
+    """A node state's live task entries ``(pc, dsp, fsp, rsp, rs0)``, one
+    per non-free task slot, in slot order."""
     from repro.core.vm.spec import ST_FREE
 
-    st = vm.state
-    if entries is None:
-        entries = []
-        for t in range(len(st.tstatus)):
-            if int(st.tstatus[t]) == ST_FREE:
-                continue
-            rsp = int(st.rsp[t])
-            rs0 = int(st.rs[t, 0]) if rsp >= 1 else 0
-            entries.append((int(st.pc[t]), int(st.dsp[t]), int(st.fsp[t]),
-                            rsp, rs0))
+    entries = []
+    for t in range(len(st.tstatus)):
+        if int(st.tstatus[t]) == ST_FREE:
+            continue
+        rsp = int(st.rsp[t])
+        rs0 = int(st.rs[t, 0]) if rsp >= 1 else 0
+        entries.append((int(st.pc[t]), int(st.dsp[t]), int(st.fsp[t]),
+                        rsp, rs0))
+    return entries
+
+
+def node_tables(vm) -> tuple[dict, dict]:
+    """A node's syscall arities ``{num: (args, ret)}`` and dictionary
+    symbols ``{name: addr}``, as the verifier takes them."""
     effects = {
         e.num: (e.args, e.ret)
         for e in getattr(vm.fios, "entries", [])
@@ -718,8 +725,20 @@ def analyze_vm(vm, entries=None) -> ProgramReport:
     symbols = {
         n: e.addr for n, e in vm.compiler.dictionary.entries.items()
     }
+    return effects, symbols
+
+
+def analyze_vm(vm, entries=None, reads: set | None = None) -> ProgramReport:
+    """Verify a host :class:`~repro.core.vm.machine.REXAVM`'s current code
+    segment from its live task entries (or explicit ``entries``), with the
+    node's syscall arities and dictionary names feeding the analysis.
+    ``reads`` is :func:`analyze_program`'s."""
+    if entries is None:
+        entries = live_entries(vm.state)
+    effects, symbols = node_tables(vm)
     return analyze_program(
-        st.cs, entries, vm.isa, vm.cfg, fios_effects=effects, symbols=symbols,
+        vm.state.cs, entries, vm.isa, vm.cfg, fios_effects=effects,
+        symbols=symbols, reads=reads,
     )
 
 

@@ -643,6 +643,8 @@ class FleetVM:
         self._elide = False
         self._analysis = None          # BackendPlan | None (auto mode)
         self._node_reports = None      # list[ProgramReport] | None
+        from repro.analysis.memo import VerdictMemo
+        self._verdicts = VerdictMemo()  # verdicts reused across start()s
         if self._auto:
             executor = "batched"
         self.kernels = self._make_kernels(executor, False)
@@ -935,11 +937,12 @@ class FleetVM:
         )
 
     def _analyze_nodes(self):
-        """Run the static verifier over every node's live task entries
-        (host-side, against the states about to be stacked)."""
-        from repro.analysis.verifier import analyze_vm
-
-        return [analyze_vm(vm) for vm in self.nodes]
+        """Run the static verifier and the branch-set walk over every
+        node's live task entries (host-side, against the states about to
+        be stacked), reusing a verdict of this or the last audit where a
+        node's entries, tables and decoded code cells match it:
+        ``(reports, branch sets per node, nodes verified)``."""
+        return self._verdicts.audit(self.nodes)
 
     def _resolve_auto(self) -> None:
         """executor="auto": verify, pick the backend, and swap kernels.
@@ -950,25 +953,19 @@ class FleetVM:
         errors are *not* rejected here (the CLI gate is the reject path);
         they run on the always-checked batched engine.
 
-        Spanned as ``fleet.audit`` with the nodes verified, the plan, and
-        ``switched`` (the kernels were swapped, so the next dispatch traces
-        and compiles).
+        Spanned as ``fleet.audit`` with ``nodes`` verified afresh and
+        ``reused`` (nodes given a memoized verdict; the two sum to the
+        fleet), the plan, and ``switched`` (the kernels were swapped, so
+        the next dispatch traces and compiles).
         """
-        from repro.analysis.feasibility import plan_backend, predict_branch_sets
+        from repro.analysis.feasibility import plan_backend
 
-        with self._tracer.span("audit", nodes=self.n) as sp:
-            reports = self._analyze_nodes()
-            branch_sets = []      # per node: the entry-trace compile key
-            aot_sets = []         # every set the engine will record (entry +
-            #                       steady-state loop re-entries, any rotation)
-            for vm, rep in zip(self.nodes, reports):
-                entry = rep.entries[0].pc if rep.entries else None
-                sets = (
-                    predict_branch_sets(vm.state.cs, entry, vm.isa)
-                    if entry is not None else ()
-                )
-                branch_sets.append(sets[0] if sets else None)
-                aot_sets.extend(sets)
+        with self._tracer.span("audit") as sp:
+            # node_sets: every set the engine will record per node (entry
+            # + steady-state loop re-entries, any rotation); the first is
+            # the entry-trace compile key.
+            reports, node_sets, verified = self._analyze_nodes()
+            branch_sets = [sets[0] if sets else None for sets in node_sets]
             plan = plan_backend(reports, branch_sets)
             self._node_reports = reports
             self._analysis = plan
@@ -990,9 +987,11 @@ class FleetVM:
                 # slice dispatches a warm specialized trace (traces_compiled
                 # stops moving during run — the equivalence tests assert it).
                 eng = self.kernels.executor.engine
-                for bs in aot_sets:
-                    eng.fn_for(bs)
-            sp.set(executor=self.executor_kind, elide_checks=self._elide,
+                for sets in node_sets:
+                    for bs in sets:
+                        eng.fn_for(bs)
+            sp.set(nodes=verified, reused=self.n - verified,
+                   executor=self.executor_kind, elide_checks=self._elide,
                    switched=switched)
 
     def analysis_stats(self) -> dict:
@@ -1005,7 +1004,7 @@ class FleetVM:
         from repro.analysis.feasibility import bail_words
 
         if self._node_reports is None:
-            self._node_reports = self._analyze_nodes()
+            self._node_reports = self._analyze_nodes()[0]
         reports = self._node_reports
         plan = self._analysis
         verdicts = {"verified": 0, "flagged": 0, "error": 0}

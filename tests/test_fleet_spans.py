@@ -125,7 +125,13 @@ def test_spans_on_the_default_path(oracle_plan, tmp_path):
     assert rounds == 6 and len(by["fleet.dispatch"]) == rounds
     assert len(by["fleet.probe"]) == traced[1]["probes"] - plain[1]["probes"]
     assert {sp[3]["readout"] for sp in spans} == {3}
-    assert audit[3]["nodes"] == 4
+    # The Auditor verifies afresh only nodes whose entries or decoded code
+    # cells it has no verdict for.  From the second read-out on, all four
+    # nodes resume at one pc inside the loop body.  SUM1 and SUM2 differ
+    # only in the loop limit, which lies before that pc and is not decoded
+    # from it.  So the second read-out verified one node, and the third
+    # reuses that verdict for all four.
+    assert audit[3]["nodes"] == 0 and audit[3]["reused"] == 4
     assert audit[3]["executor"] == "oracle" and audit[3]["switched"] == 0
     nbytes = plain[1]["h2d_bytes"] // 2
     assert restack[3]["bytes"] == by["fleet.sync"][0][3]["bytes"] == nbytes
@@ -150,8 +156,9 @@ def test_ring_records_nest_on_the_profiler_clock(oracle_plan, tmp_path):
                 "warp": "dispatch"}[ev["name"]]
         assert parent(ev) == want, ev
     audit = next(ev for ev in ring if ev["name"] == "audit")
-    # The first start() swaps the fleet's initial kernels for the plan's.
-    assert audit["counts"] == {"nodes": 4, "executor": "oracle",
+    # The first start() swaps the fleet's initial kernels for the plan's,
+    # and verifies SUM1 and SUM2 once each.
+    assert audit["counts"] == {"nodes": 2, "reused": 2, "executor": "oracle",
                                "elide_checks": False, "switched": True}
     # The ring's clock is the profiler's: each record lands on its event.
     spans, t0 = host_spans(tmp_path)
